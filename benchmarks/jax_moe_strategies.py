@@ -141,12 +141,13 @@ from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
 from repro.core import autotune, strategy
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 from repro.launch.analysis import collective_bytes
 
 E, k, d, de = 16, 2, 256, 512
 moe = MoEConfig(num_experts=E, top_k=k, d_expert=de, micro_slices=4)
 params = moe_mod.moe_init(jax.random.PRNGKey(0), d, moe, "swiglu", jnp.bfloat16)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, S = 8, 64
 x = jax.random.normal(jax.random.PRNGKey(1), (B, S, d), jnp.bfloat16)
 
@@ -207,7 +208,10 @@ print(json.dumps({"rows": rows, "auto_family": family_plan.family,
 
 
 def run():
-    env = dict(os.environ, PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
+    # a CPU rehearsal by design: the child never competes with a parent
+    # that may hold the chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=1200)

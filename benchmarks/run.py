@@ -67,14 +67,11 @@ def main() -> None:
              f"fse_dp {u[('2x2','fse_dp_paired')]:.3f}->{u[('4x4','fse_dp_paired')]:.3f}")
 
     t0 = time.time()
-    try:
-        rows = jax_moe_strategies.run()
-        fse = next(r for r in rows if r[0] == "fse_dp")
-        ep = next(r for r in rows if r[0] == "ep")
-        _summary("jax_moe_strategies", t0,
-                 f"fse_dp a2a={fse[3]}B permute={fse[4]}B | ep a2a={ep[3]}B")
-    except Exception as e:  # pragma: no cover
-        _summary("jax_moe_strategies", t0, f"SKIPPED ({e})")
+    rows = jax_moe_strategies.run()
+    fse = next(r for r in rows if r[0] == "fse_dp")
+    ep = next(r for r in rows if r[0] == "ep")
+    _summary("jax_moe_strategies", t0,
+             f"fse_dp a2a={fse[3]}B permute={fse[4]}B | ep a2a={ep[3]}B")
 
     t0 = time.time()
     rows = roofline.run()

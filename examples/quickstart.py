@@ -47,7 +47,10 @@ def main():
     print("== 2. FSE-DP distributed == dense oracle (8 fake devices) ==")
     script = os.path.join(os.path.dirname(__file__), "..", "tests",
                           "distributed_scripts", "fsedp_modes.py")
-    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
+    # a CPU rehearsal by design (8 fake devices): the child never
+    # competes with this process for an accelerator
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, script], env=env, capture_output=True,
                          text=True, timeout=900)

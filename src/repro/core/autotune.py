@@ -135,21 +135,26 @@ class HardwareProfile:
                    ndp_bw=0.0 if ndp is None else ndp.gbps)
 
     @classmethod
-    def from_tpu(cls) -> "HardwareProfile":
-        """v5e-class constants shared with ``launch.analysis``."""
+    def from_tpu(cls, device_kind: str) -> "HardwareProfile":
+        """One TPU chip of ``device_kind`` (as JAX reports it), from the
+        published peaks in ``launch.analysis.TPU_PEAKS``; a kind the
+        table does not hold raises.  The kernel working-set budget is
+        the streamed-MoE kernel's own (``VMEM_BUDGET_BYTES``)."""
+        from repro.kernels.streamed_moe import VMEM_BUDGET_BYTES
         from repro.launch import analysis
-        return cls(name="tpu-v5e", peak_flops=analysis.PEAK_FLOPS,
-                   mem_bw=analysis.HBM_BW, link_bw=analysis.ICI_BW,
-                   link_latency=1e-6, vmem_bytes=analysis.VMEM_BYTES)
+        peaks = analysis.tpu_peaks(device_kind)
+        return cls(name=device_kind, peak_flops=peaks["bf16_flops"],
+                   mem_bw=peaks["hbm_bw"], link_bw=peaks["ici_link_bw"],
+                   link_latency=1e-6, vmem_bytes=VMEM_BUDGET_BYTES)
 
     @classmethod
     def detect(cls) -> "HardwareProfile":
-        try:
-            import jax
-            if jax.default_backend() == "tpu":
-                return cls.from_tpu()
-        except Exception:  # pragma: no cover
-            pass
+        """The attached TPU's profile (raises on a TPU kind with no
+        published peaks), else the chiplet prototype's."""
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            return cls.from_tpu(dev.device_kind)
         return cls.from_chiplet()
 
 
@@ -642,30 +647,13 @@ def _micro_candidates(de_loc: int, configured: int) -> List[int]:
 # ---------------------------------------------------------------------------
 
 
-# the one tile-rounding rule, shared with the kernel so planner and
-# lowering can never disagree on a requested tile (satellite of the
-# quantized-streaming work: previously duplicated here)
-from repro.kernels.streamed_moe import fit_tile as _fit_tile  # noqa: E402
-
-
-def tile_vmem_bytes(Tc: int, Ti: int, Tj: int, Tk: int, gated: bool,
-                    dtype_bytes: int = 2,
-                    weight_bytes: Optional[int] = None) -> int:
-    """VMEM working set of one ``streamed_moe_kernel`` grid step.
-
-    Streamed blocks (x + weights) are double-buffered by Pallas; the
-    fp32 output block and the pre-activation scratch are not.
-    ``weight_bytes`` is the streamed weight-block byte width (quantized
-    storage; ``None`` = ``dtype_bytes``) — 1-byte formats also stream
-    their per-output-channel fp32 scale rows.
-    """
-    n_up = 2 if gated else 1
-    wb = dtype_bytes if weight_bytes is None else weight_bytes
-    streamed = Tc * Ti * dtype_bytes + n_up * Ti * Tk * wb + Tk * Tj * wb
-    if wb == 1:                         # int8/fp8 scale rows ride along
-        streamed += (n_up * Tk + Tj) * 4
-    resident = Tc * Tj * 4 + (1 + (1 if gated else 0)) * Tc * Tk * 4
-    return 2 * streamed + resident
+# the kernel's own tiling rules (rounding, VMEM working set, defaults),
+# so planner and lowering can never disagree on a tile
+from repro.kernels.streamed_moe import (VMEM_BUDGET_BYTES,  # noqa: E402
+                                        default_tiles as _kernel_default_tiles,
+                                        fit_tile as _fit_tile,
+                                        resolve_tiles as _kernel_resolve_tiles,
+                                        vmem_bytes as _kernel_vmem_bytes)
 
 
 def kernel_tile_cost(E: int, C: int, d: int, m: int, Tc: int, Tj: int,
@@ -689,22 +677,7 @@ def kernel_tile_cost(E: int, C: int, d: int, m: int, Tc: int, Tj: int,
            + E * Cp * d * 4 * (m / Tk))                             # out revisits
     t = flops / profile.peak_flops + hbm / profile.mem_bw
     return {"t": t, "flops": flops, "hbm": hbm,
-            "vmem": tile_vmem_bytes(Tc, Ti, Tj, Tk, gated, dtype_bytes,
-                                    weight_bytes)}
-
-
-def default_tiles(C: int, d: int, m: int, dtype_bytes: int = 2,
-                  weight_bytes: Optional[int] = None) -> Tuple[int, int, int]:
-    """The (Tc, Tj, Tk) the kernel picks with no explicit opts.
-
-    ``weight_bytes`` mirrors the kernel's rule exactly: the default
-    hidden tile is sized off the *streamed operand's* itemsize, so
-    quantized weights fit proportionally larger Tk per VMEM block."""
-    from repro.kernels.streamed_moe import DEFAULT_TOKEN_TILE, VMEM_BLOCK_BYTES
-    wb = dtype_bytes if weight_bytes is None else weight_bytes
-    Tc = min(DEFAULT_TOKEN_TILE, max(C, 1))
-    Tk = _fit_tile(m, max(1, VMEM_BLOCK_BYTES // max(1, d * wb)))
-    return Tc, d, Tk
+            "vmem": _kernel_vmem_bytes(Tc, Tj, Tk, gated, dtype_bytes, wb)}
 
 
 def plan_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
@@ -723,7 +696,11 @@ def plan_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
     """
     profile = profile or HardwareProfile.detect()
     gated = activation == "swiglu"
-    dTc, dTj, dTk = default_tiles(C, d, m, dtype_bytes, weight_bytes)
+    # the tiles the kernel lowers with no opts, sized off the streamed
+    # operand's itemsize (quantized weights fit larger hidden tiles)
+    dTc, dTj, dTk = _kernel_default_tiles(
+        C, d, m, gated=gated, x_bytes=dtype_bytes,
+        w_bytes=dtype_bytes if weight_bytes is None else weight_bytes)
 
     tc_cands = sorted({dTc} | {t for t in (32, 64, 128, 256) if t <= max(C, 1)})
     tk_cands = sorted({dTk} | {t for t in {m, m // 2, m // 4} if t >= 1})
@@ -748,7 +725,7 @@ def plan_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
                     best = (key, (Tc, Tj, Tk), sc)
     (_, (Tc, Tj, Tk), sc) = best
     return {"token_tile": Tc,
-            "dmodel_tile": None if Tj == d else Tj,
+            "dmodel_tile": None if Tj == dTj else Tj,
             "dexpert_tile": None if Tk == dTk else Tk,
             "predicted_s": sc["t"], "vmem_bytes": int(sc["vmem"]),
             "fits": sc["vmem"] <= profile.vmem_bytes}
@@ -758,17 +735,10 @@ def plan_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
 # measured tile autotune (on-disk memoized)
 # ---------------------------------------------------------------------------
 
-def _repo_root() -> str:
-    here = os.path.abspath(os.path.dirname(__file__))
-    cand = os.path.dirname(os.path.dirname(os.path.dirname(here)))
-    if os.path.exists(os.path.join(cand, "pyproject.toml")):
-        return cand
-    return os.getcwd()
-
-
 def cache_dir() -> str:
+    from repro.launch.compile_cache import repo_root
     return os.environ.get("REPRO_AUTOTUNE_CACHE",
-                          os.path.join(_repo_root(), "artifacts", "autotune"))
+                          os.path.join(repo_root(), "artifacts", "autotune"))
 
 
 _MEASURED: Dict[str, dict] = {}
@@ -830,13 +800,22 @@ def measured_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
 
     analytic = plan_kernel_tiles(E, C, d, m, activation, profile,
                                  dtype_bytes, weight_bytes)
-    cands: List[Dict[str, int]] = [{}]                    # kernel defaults
+    extra: List[Dict[str, int]] = []
     opt = {k: v for k, v in analytic.items()
            if k in ("token_tile", "dmodel_tile", "dexpert_tile") and v}
     if opt:
-        cands.append(opt)
+        extra.append(opt)
     if m > 1:
-        cands.append({"dexpert_tile": max(1, m // 2)})
+        extra.append({"dexpert_tile": max(1, m // 2)})
+    gated = activation == "swiglu"
+    # the kernel defaults, plus each alternative whose lowered tiles fit
+    # the kernel's VMEM budget
+    cands: List[Dict[str, int]] = [{}] + [
+        o for o in extra
+        if _kernel_vmem_bytes(
+            *_kernel_resolve_tiles(C, d, m, gated=gated, x_bytes=dtype_bytes,
+                                   w_bytes=dtype_bytes, **o),
+            gated, dtype_bytes, dtype_bytes) <= VMEM_BUDGET_BYTES]
 
     dt = jnp.float32 if dtype_bytes == 4 else jnp.bfloat16
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -851,20 +830,20 @@ def measured_kernel_tiles(E: int, C: int, d: int, m: int, activation: str,
         def fn(xe, wg, wu, wd, _opts=opts):
             with kops.use_kernels(True):
                 return kops.streamed_moe(xe, wg, wu, wd, activation, **_opts)
+        # every candidate's tiles are LANE-aligned by fit_tile, and the
+        # alternatives fit the VMEM budget, so a lowering failure is a bug:
+        # it raises
         jf = jax.jit(fn)
-        try:
-            compiled = jf.lower(xe, wg, wu, wd).compile()
-            flops = float(cost_dict(compiled).get("flops", 0.0))
+        compiled = jf.lower(xe, wg, wu, wd).compile()
+        flops = float(cost_dict(compiled).get("flops", 0.0))
+        jax.block_until_ready(jf(xe, wg, wu, wd))
+        ts = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
             jax.block_until_ready(jf(xe, wg, wu, wd))
-            ts = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(jf(xe, wg, wu, wd))
-                ts.append(time.perf_counter() - t0)
-            rows.append({"opts": opts, "ms": statistics.median(ts) * 1e3,
-                         "flops": flops})
-        except Exception as e:  # pragma: no cover — candidate fails to lower
-            rows.append({"opts": opts, "ms": float("inf"), "error": str(e)})
+            ts.append(time.perf_counter() - t0)
+        rows.append({"opts": opts, "ms": statistics.median(ts) * 1e3,
+                     "flops": flops})
 
     best = min(rows, key=lambda r: r["ms"])
     entry = {"opts": best["opts"], "ms": best["ms"],

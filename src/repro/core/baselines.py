@@ -31,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.parallel import meshctx
 from . import gating
-from .fse_dp import _expert_partial, _route, shard_map, pmean_all
+from .fse_dp import _expert_partial, _route, shard_map
 
 
 def _capacity(T_loc: int, moe: MoEConfig) -> int:
@@ -103,7 +103,7 @@ def _local_ep(x, wr, w_g, w_u, w_d, *, moe, activation, axis, P_, pm_axes,
     yrecv = yrecv.reshape(E, C, d)
     y = jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
                    yrecv.astype(jnp.float32))
-    aux = pmean_all(gating.aux_load_balance_loss(routing, E), pm_axes)
+    aux = jax.lax.pmean(gating.aux_load_balance_loss(routing, E), pm_axes)
     return y.reshape(B, S, d).astype(x.dtype), aux
 
 
@@ -186,7 +186,7 @@ def _local_tp(x, wr, w_g, w_u, w_d, *, moe, activation, axis, P_, pm_axes,
     y = jnp.einsum("tec,ecd->td", combine.astype(jnp.float32), ye)
     y = jax.lax.psum(y, axis)
     aux = gating.aux_load_balance_loss(routing, moe.num_experts)
-    aux = pmean_all(aux, pm_axes)
+    aux = jax.lax.pmean(aux, pm_axes)
     return y.reshape(B, S, d).astype(x.dtype), aux
 
 

@@ -40,7 +40,6 @@ Three execution modes, chosen statically from the token layout
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -51,41 +50,17 @@ from repro.kernels import ops as kops
 from repro.parallel import meshctx
 from . import gating
 
-try:  # jax>=0.6 exposes shard_map at top level
-    _jax_shard_map = jax.shard_map  # type: ignore[attr-defined]
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _jax_shard_map  # type: ignore
 
-# replication-checker kwarg is check_rep (jax<=0.5) / check_vma (jax>=0.6)
-_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                  if k in inspect.signature(_jax_shard_map).parameters), None)
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, **kw):
-    """shard_map with the static replication checker off by default:
+def shard_map(fn, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes checker off:
     ``pallas_call`` (the streamed-MoE kernel inside the body) has no
-    replication rule, and jax 0.4.x's checker rewrite of an enclosing
-    ``lax.scan`` mis-infers the aux-loss carry as non-replicated even on
-    the pure-jnp path (the seed dry-run failure).  All replicated outputs
-    here (aux, index-mode y) are explicitly pmean/psum'd, so the check is
-    redundant.  Callers can re-enable it via the keyword."""
-    if _CHECK_KW and _CHECK_KW not in kw:
-        kw[_CHECK_KW] = False
-    return _jax_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-
-def pmean_all(x, axes):
-    """pmean over ``axes`` regardless of which of them x varies on
-    (pvary the missing ones first — vma-safe)."""
-    try:
-        vma = jax.typeof(x).vma
-        missing = tuple(a for a in axes if a not in vma)
-        if missing:
-            x = jax.lax.pvary(x, missing)
-    except Exception:
-        pass
-    return jax.lax.pmean(x, axes)
+    vma rule.  All replicated outputs here (aux, index-mode y) are
+    explicitly pmean/psum'd, so the check is redundant.  With the
+    checker off no value is typed invariant, so a plain ``pmean``
+    transposes to a plain ``pmean`` — inserting ``pvary`` first would
+    break the gradient through the ring."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -222,7 +197,7 @@ def _local_moe_stream(x, wr, w_g, w_u, w_d, *, moe, activation, axis, P_,
                       micro_slices or moe.micro_slices, kopts, order)
     y = combine(ye.reshape(moe.num_experts, -1, d))
     aux = gating.aux_load_balance_loss(routing, moe.num_experts)
-    aux = pmean_all(aux, pm_axes)
+    aux = jax.lax.pmean(aux, pm_axes)
     return y.reshape(B, S, d).astype(x.dtype), aux
 
 
@@ -247,7 +222,7 @@ def _local_moe_index(x, wr, w_g, w_u, w_d, *, moe, activation, axis, P_,
     y = jnp.zeros((T, d), jnp.float32)
     y = jax.lax.dynamic_update_slice_in_dim(y, y_loc, r * T_loc, 0)
     y = jax.lax.psum(y, axis).astype(x.dtype)
-    aux = pmean_all(gating.aux_load_balance_loss(routing, moe.num_experts), pm_axes)
+    aux = jax.lax.pmean(gating.aux_load_balance_loss(routing, moe.num_experts), pm_axes)
     return y.reshape(B, S, d), aux
 
 
@@ -269,7 +244,7 @@ def _local_moe_slice(x, wr, w_g, w_u, w_d, *, moe, activation, axis, P_,
     y = combine(ye)
     y = jax.lax.psum(y, axis)
     aux = gating.aux_load_balance_loss(routing, moe.num_experts)
-    aux = pmean_all(aux, pm_axes)
+    aux = jax.lax.pmean(aux, pm_axes)
     return y.reshape(B, S, d).astype(x.dtype), aux
 
 

@@ -61,7 +61,8 @@ def flash_attention_kernel(q, k, v, *, q_tile=128, k_tile=128,
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        from .ops import interpret_default
+        interpret = interpret_default()
     tq = min(q_tile, Sq)
     tk = min(k_tile, Sk)
     padq = (-Sq) % tq

@@ -2,7 +2,11 @@
 
 ``use_kernels(False)`` (or the REPRO_NO_PALLAS env var) routes every op
 to its pure-jnp oracle — the dry-run path uses this so the 512-device
-SPMD compile sees plain XLA ops.
+SPMD compile sees plain XLA ops.  The env var is refused on a TPU, so a
+chip run can never measure the oracle in place of the kernels.
+
+:func:`interpret_default` is the one rule for how a kernel lowers: in
+the Pallas interpreter on the CPU backend, natively everywhere else.
 
 ``streamed_moe`` is the dispatch layer the model code calls: both the
 FSE-DP ring step (``repro.core.fse_dp._expert_partial``) and the
@@ -25,8 +29,15 @@ from .streamed_moe import streamed_moe_kernel
 from .flash_attention import flash_attention_kernel
 from .ssd import ssd_intra_chunk_kernel
 
-_USE = contextvars.ContextVar("repro_use_pallas",
-                              default=not bool(os.environ.get("REPRO_NO_PALLAS")))
+def interpret_default() -> bool:
+    """Whether a kernel called with ``interpret=None`` runs in the Pallas
+    interpreter: only on the CPU backend.  Every kernel resolves its
+    default here, so none is quietly interpreted on an accelerator."""
+    return jax.default_backend() == "cpu"
+
+
+# None = not set in this context: the REPRO_NO_PALLAS default applies
+_USE = contextvars.ContextVar("repro_use_pallas", default=None)
 
 
 @contextlib.contextmanager
@@ -38,8 +49,21 @@ def use_kernels(enabled: bool):
         _USE.reset(tok)
 
 
+def _env_default() -> bool:
+    if not os.environ.get("REPRO_NO_PALLAS"):
+        return True
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "REPRO_NO_PALLAS is set on a TPU backend: the chip path runs "
+            "the Pallas kernels, never the jnp oracle in their place — "
+            "unset it (or use kernels.ops.use_kernels(False) explicitly "
+            "for a reference computation)")
+    return False
+
+
 def kernels_enabled() -> bool:
-    return _USE.get()
+    enabled = _USE.get()
+    return _env_default() if enabled is None else enabled
 
 
 # ---------------------------------------------------------------------------

@@ -33,24 +33,94 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_TOKEN_TILE = 128
-# auto-tiling target: keep one streamed weight block under this many bytes
-# of VMEM (w_g + w_u + w_d + double-buffering must fit in ~16 MB/core)
-VMEM_BLOCK_BYTES = 4 * 1024 * 1024
+# Mosaic block rule: the last two dims of every block are multiples of
+# (SUBLANE, LANE) or span the whole array dim
+LANE, SUBLANE = 128, 8
+# TPU v5e's default scoped-VMEM limit (what one kernel may hold in fast
+# memory unless it asks for more).  Tiles are planned against 3/4 of it;
+# the rest is left to Mosaic's own temporaries (dot results, casts).
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
+
+
+def _lane_tiles(dim: int):
+    """Tiles Mosaic accepts on a lane axis of width ``dim``, largest
+    first: the divisors that are multiples of LANE, and ``dim`` itself."""
+    return [t for t in range(dim, 0, -1)
+            if dim % t == 0 and (t % LANE == 0 or t == dim)]
 
 
 def fit_tile(dim: int, req: int) -> int:
-    """Largest divisor of ``dim`` that is <= ``req`` (>= 1).
+    """Tile for a d_model or d_expert block axis: the largest divisor of
+    ``dim`` that is a multiple of LANE and <= ``req``; the smallest such
+    divisor when none is <= ``req``; ``dim`` itself when it has none.
 
-    The one tile-rounding rule shared by this kernel and the
-    ``core.autotune`` planner, so requested tiles can never drift
-    between what the planner costs and what the kernel lowers."""
-    t = max(1, min(int(req), dim))
-    while dim % t:
-        t -= 1
-    return t
+    Every d/m tile is the last (lane) dim of some block (w_up's Tk,
+    w_down's and the output's Tj), so LANE alignment covers the sublane
+    rule too.  This is the one rounding rule shared by the kernel and
+    the ``core.autotune`` planner, so the two cannot drift apart."""
+    tiles = _lane_tiles(dim)
+    below = [t for t in tiles if t <= req]
+    return below[0] if below else tiles[-1]
 
 
-_fit_tile = fit_tile  # backward-compat alias
+def token_tile_for(C: int, req: int) -> int:
+    """Token-tile rows (a sublane axis): all of ``C`` when it fits in
+    ``req``, else ``req`` rounded down to a multiple of SUBLANE (capacity
+    rows are padded up to a multiple of the tile)."""
+    if C <= req:
+        return max(C, 1)
+    return max(SUBLANE, req - req % SUBLANE)
+
+
+def vmem_bytes(Tc: int, Tj: int, Tk: int, gated: bool, x_bytes: int = 2,
+               w_bytes: int = 2) -> int:
+    """VMEM working set of one grid step (``Ti == Tj``): every streamed
+    block (x, the weight blocks, 1-byte formats' fp32 scale rows) and the
+    fp32 output block double-buffered by the Pallas pipeline, plus the
+    fp32 pre-activation scratch and, for 1-byte weights, the fp32
+    dequantized copies the kernel makes in VMEM."""
+    n_up = 2 if gated else 1
+    streamed = Tc * Tj * x_bytes + (n_up * Tj * Tk + Tk * Tj) * w_bytes
+    dequant = 0
+    if w_bytes == 1:
+        streamed += (n_up * Tk + Tj) * 4
+        dequant = (n_up * Tj * Tk + Tk * Tj) * 4
+    out = Tc * Tj * 4
+    scratch = n_up * Tc * Tk * 4
+    return 2 * (streamed + out) + scratch + dequant
+
+
+def default_tiles(C: int, d: int, m: int, *, gated: bool, x_bytes: int = 2,
+                  w_bytes: int = 2, token_tile: int = DEFAULT_TOKEN_TILE):
+    """(Tc, Tj, Tk) the kernel lowers with no explicit d/m tiles: d_model
+    whole if any hidden tile then fits ``VMEM_BUDGET_BYTES`` (tiling
+    d_model makes the up/gate GEMMs recompute once per output tile), with
+    the largest fitting hidden tile; else the largest fitting d_model
+    tile; else the smallest aligned tiles."""
+    Tc = token_tile_for(C, token_tile)
+    tks = _lane_tiles(m)
+    for Tj in _lane_tiles(d):
+        for Tk in tks:
+            if vmem_bytes(Tc, Tj, Tk, gated, x_bytes,
+                          w_bytes) <= VMEM_BUDGET_BYTES:
+                return Tc, Tj, Tk
+    return Tc, _lane_tiles(d)[-1], tks[-1]
+
+
+def resolve_tiles(C: int, d: int, m: int, *, gated: bool, x_bytes: int = 2,
+                  w_bytes: int = 2, token_tile: int = DEFAULT_TOKEN_TILE,
+                  dmodel_tile: int | None = None,
+                  dexpert_tile: int | None = None):
+    """(Tc, Tj, Tk) the kernel lowers for these options: the defaults,
+    with each requested d/m tile rounded by :func:`fit_tile`."""
+    Tc, Tj, Tk = default_tiles(C, d, m, gated=gated, x_bytes=x_bytes,
+                               w_bytes=w_bytes, token_tile=token_tile)
+    if dmodel_tile is not None:
+        Tj = fit_tile(d, dmodel_tile)
+    if dexpert_tile is not None:
+        Tk = fit_tile(m, dexpert_tile)
+    return Tc, Tj, Tk
 
 
 def _kernel(*refs, activation, quantized, nI, C, Tc):
@@ -137,9 +207,11 @@ def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
 
     ``dmodel_tile`` tiles d_model on both sides of the expert FFN
     (contraction of the up-projection and output of the down-projection);
-    ``dexpert_tile`` tiles the micro-slice hidden dim.  Defaults keep
-    d_model whole and cap the hidden tile so one weight block stays under
-    ``VMEM_BLOCK_BYTES``.  Requested tiles are rounded down to divisors.
+    ``dexpert_tile`` tiles the micro-slice hidden dim.  Defaults
+    (:func:`default_tiles`) keep d_model whole where they can and pick
+    the largest hidden tile whose whole working set fits
+    ``VMEM_BUDGET_BYTES``.  Requested tiles are rounded by
+    :func:`fit_tile` to LANE-aligned divisors.
 
     Trade-off: with ``dmodel_tile < d`` the up/gate GEMMs are recomputed
     once per output-d tile (the activation between the two GEMMs forces
@@ -157,21 +229,19 @@ def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
     if activation not in ("swiglu", "relu2", "gelu"):
         raise ValueError(f"unknown activation {activation!r}")
     if interpret is None:
-        # only TPU lowers this kernel natively (pltpu.VMEM scratch);
-        # everything else (cpu, gpu) runs the interpreter
-        interpret = jax.default_backend() != "tpu"
+        from .ops import interpret_default
+        interpret = interpret_default()
 
-    Tc = min(token_tile, max(C, 1))
+    Tc, Tj, Tk = resolve_tiles(C, d, m, gated=gated,
+                               x_bytes=jnp.dtype(xe.dtype).itemsize,
+                               w_bytes=jnp.dtype(w_u.dtype).itemsize,
+                               token_tile=token_tile, dmodel_tile=dmodel_tile,
+                               dexpert_tile=dexpert_tile)
+    Ti = Tj
     pad = (-C) % Tc
     if pad:
         xe = jnp.pad(xe, ((0, 0), (0, pad), (0, 0)))
     Cp = C + pad
-
-    itemsize = jnp.dtype(w_u.dtype).itemsize    # 1 for int8/fp8 operands
-    if dexpert_tile is None:
-        dexpert_tile = max(1, VMEM_BLOCK_BYTES // max(1, d * itemsize))
-    Tk = fit_tile(m, dexpert_tile)
-    Tj = Ti = fit_tile(d, dmodel_tile if dmodel_tile is not None else d)
     nI = d // Ti
     grid = (E, Cp // Tc, d // Tj, m // Tk, nI)
 

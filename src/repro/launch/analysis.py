@@ -11,11 +11,34 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-# v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
-VMEM_BYTES = 16 * 2 ** 20    # per-core fast memory (kernel working-set budget)
+# Published peaks of one TPU chip, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (200 GB/s, 50 GB/s on each of the 2x2 torus's 4 links).
+TPU_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+
+
+def tpu_peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of one chip of ``device_kind``; a kind the
+    table does not hold is an error, never a default."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for TPU device kind "
+                         f"{device_kind!r}; add them to "
+                         f"launch.analysis.TPU_PEAKS with their source"
+                         ) from None
+
+
+# the dry-run's roofline models a pod of v5e chips
+_V5E = TPU_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["bf16_flops"]   # FLOP/s
+HBM_BW = _V5E["hbm_bw"]           # bytes/s
+ICI_BW = _V5E["ici_link_bw"]      # bytes/s per link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -33,12 +56,9 @@ _OP_RE = re.compile(
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` normalized to a flat dict — jax returns
-    a list with one dict per device program on some versions/backends."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a dict (empty where the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def lowering_cost(fn, *args) -> Dict[str, float]:

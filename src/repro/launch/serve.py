@@ -146,10 +146,12 @@ def main():
     import jax
     import numpy as np
     from repro.configs import get_config, reduced_config
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.core.strategy import ExecutionSpec
     from repro.models import api
     from repro.serving import Engine, ServeConfig
 
+    configure_compile_cache()
     spec = build_spec(args)
     roundtrip = ExecutionSpec.from_json(spec.to_json())
     if roundtrip != spec:

@@ -27,9 +27,11 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config, reduced_config
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.data import DataConfig
     from repro.training import TrainConfig, train
 
+    configure_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
         cfg = cfg.replace(dtype="float32")

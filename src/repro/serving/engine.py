@@ -421,6 +421,12 @@ class Engine:
         self.requests[rid] = st
         return rid
 
+    def _sample_row(self, r: RequestState, logits) -> int:
+        """Sample request ``r``'s next token from its logits row: a
+        chunked-prefill request's first token, and every decode token.
+        The seam a caller overrides to inspect the logits behind them."""
+        return self._sample(logits)
+
     def _sample(self, logits) -> int:
         lf = np.asarray(logits, np.float32)
         if self.scfg.temperature <= 0:
@@ -610,7 +616,7 @@ class Engine:
                 continue
             # prompt fully cached: unembed just this row's final chunk
             # position, emit the first token, and join decode
-            first = self._sample(hid[r.slot, k_r - 1] @ head)
+            first = self._sample_row(r, hid[r.slot, k_r - 1] @ head)
             r.generated.append(int(first))
             r.phase = "decode"
             r.progress = 0
@@ -744,7 +750,7 @@ class Engine:
             self.stats["host_syncs"] += 1
             logits = jax.device_get(logits)
         for r in finish:
-            tok = self._sample(logits[r.slot, 0])
+            tok = self._sample_row(r, logits[r.slot, 0])
             r.generated.append(tok)
             out.append((r.rid, tok))
             self.stats["tokens_emitted"] += 1

@@ -6,9 +6,10 @@ from repro.configs.shapes import ShapeSpec
 from repro.launch.steps import build_step
 from repro.launch import analysis
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 from repro.kernels import ops as kops
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for arch in ["granite-moe-1b-a400m", "jamba-v0.1-52b", "mamba2-370m", "whisper-base", "internvl2-2b"]:
     cfg = reduced_config(arch)
     if cfg.moe is not None:

@@ -15,13 +15,14 @@ from repro.core import autotune, strategy as strat, trajectory
 from repro.core.strategy import ExecutionSpec
 from repro.models import moe as moe_mod
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 
 moe = MoEConfig(num_experts=8, top_k=2, d_expert=64, capacity_factor=4.0,
                 micro_slices=2)
 D = 32
 params = moe_mod.moe_init(jax.random.PRNGKey(0), D, moe, "swiglu",
                           jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, D), jnp.float32)
 
 with meshctx.with_mesh(mesh):

@@ -12,12 +12,13 @@ from repro.configs.base import MoEConfig
 from repro.core import strategy
 from repro.models import moe as moe_mod
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 
 E, k, d, de = 8, 2, 32, 64
 moe = MoEConfig(num_experts=E, top_k=k, d_expert=de, capacity_factor=E / k,
                 micro_slices=2)
 params = moe_mod.moe_init(jax.random.PRNGKey(1), d, moe, "swiglu", jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, d), jnp.float32)
 
 

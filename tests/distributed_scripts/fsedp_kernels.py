@@ -16,9 +16,10 @@ from repro.core import fse_dp, strategy
 from repro.kernels import ops as kops
 from repro.models import moe as moe_mod
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 
 E, k, d, de = 8, 2, 32, 64
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, d), jnp.float32)
 
 

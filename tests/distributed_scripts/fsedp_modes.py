@@ -7,13 +7,14 @@ from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
 from repro.core import gating, fse_dp, strategy
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 
 E, k, d, de = 8, 2, 32, 64
 moe = MoEConfig(num_experts=E, top_k=k, d_expert=de, capacity_factor=E/k, micro_slices=2)
 key = jax.random.PRNGKey(1)
 params = moe_mod.moe_init(key, d, moe, "swiglu", jnp.float32)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, S = 4, 16
 x = jax.random.normal(jax.random.PRNGKey(2), (B, S, d), jnp.float32)
 

@@ -15,6 +15,7 @@ from repro.core.strategy import ExecutionSpec
 from repro.models import moe as moe_mod, transformer
 from repro.models.layers import apply_norm
 from repro.parallel import meshctx
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import constrain_seq_sharded
 
 cfg = ModelConfig(
@@ -24,7 +25,7 @@ cfg = ModelConfig(
                   capacity_factor=4.0, micro_slices=2, impl="fse_dp"),
     dtype="float32")
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 params = transformer.init_lm(jax.random.PRNGKey(0), cfg)
 B, S = 4, 16
 tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)

@@ -155,8 +155,15 @@ def test_kernel_opts_off_is_empty():
 # ---------------------------------------------------------------------------
 
 
+def test_tpu_profile_reads_published_peaks_and_refuses_unknown_kinds():
+    v5e = HardwareProfile.from_tpu("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.mem_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        HardwareProfile.from_tpu("TPU v99")
+
+
 def test_tile_planner_prefers_defaults_when_they_fit():
-    profile = HardwareProfile.from_tpu()
+    profile = HardwareProfile.from_tpu("TPU v5 lite")
     tiles = plan_kernel_tiles(8, 64, 256, 128, "swiglu", profile)
     assert tiles["fits"]
     assert tiles["dmodel_tile"] is None          # d_model kept whole
@@ -169,7 +176,7 @@ def test_tile_planner_shrinks_under_tiny_budget():
                               vmem_bytes=2 * 2 ** 20)
     tiles = plan_kernel_tiles(8, 256, 1024, 1024, "swiglu", profile)
     big = plan_kernel_tiles(8, 256, 1024, 1024, "swiglu",
-                            HardwareProfile.from_tpu())
+                            HardwareProfile.from_tpu("TPU v5 lite"))
     assert tiles["vmem_bytes"] < big["vmem_bytes"]
 
 

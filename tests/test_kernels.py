@@ -112,3 +112,26 @@ def test_ssd_intra_chunk(b, nc, c, h, p, n):
     wy, ws = ref.ssd_intra_chunk_ref(xc, Bc, Cc, Ac, Acum)
     np.testing.assert_allclose(gy, wy, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(gs, ws, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the one kernel-selection rule (kernels.ops)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", False)])
+def test_every_kernel_interprets_only_on_cpu(monkeypatch, backend, interpret):
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.interpret_default() is interpret
+
+
+def test_no_pallas_env_switch_is_refused_on_tpu(monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setenv("REPRO_NO_PALLAS", "1")
+    assert ops.kernels_enabled() is False            # the CPU oracle path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="REPRO_NO_PALLAS"):
+        ops.kernels_enabled()
+    with ops.use_kernels(False):                     # explicit: still allowed
+        assert ops.kernels_enabled() is False

@@ -95,11 +95,13 @@ def test_kernel_matches_quant_oracle(act, wd):
 
 
 @pytest.mark.parametrize("wd", ["int8", "fp8"])
-@pytest.mark.parametrize("dm_tile,de_tile", [(8, 8), (16, 12), (32, 24)])
+@pytest.mark.parametrize("dm_tile,de_tile", [(128, 128), (128, 256),
+                                              (256, 128)])
 def test_kernel_quant_tiled_matches_oracle(wd, dm_tile, de_tile):
     """Scale side-operands must block-index correctly under d_model and
-    d_expert tiling (C=37 with token_tile=16 also covers row masking)."""
-    xe, wg, wu, wd_ = _shapes()
+    d_expert tiling (LANE-aligned tiles; C=37 with token_tile=16 also
+    covers row masking)."""
+    xe, wg, wu, wd_ = _shapes(E=2, d=256, m=512)
     with ops.use_kernels(True):
         got = ops.streamed_moe(xe, wg, wu, wd_, "swiglu", weight_dtype=wd,
                                token_tile=16, dmodel_tile=dm_tile,

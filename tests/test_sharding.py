@@ -96,3 +96,37 @@ def test_param_specs_cover_all_leaves(arch):
                 size = MESH.shape[ax] if isinstance(ax, str) else \
                     int(jnp.prod(jnp.asarray([MESH.shape[a] for a in ax])))
                 assert dim % size == 0, (key, spec, leaf.shape)
+
+
+def test_make_mesh_gives_auto_axes():
+    """jax.make_mesh defaults to Explicit axes, under which the sharding
+    constraints above refuse to name an axis; the repo's helper gives
+    every axis the Auto type."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax_cache"])
+def test_compile_cache_placed_from_outside(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, where set, stands and nothing is set in
+    code; otherwise the cache goes to a fixed path in the checkout."""
+    import os
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.configure_compile_cache()
+        if env_dir is None:
+            assert got == os.path.join(compile_cache.repo_root(),
+                                       "artifacts", "jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -35,11 +35,13 @@ def test_kernel_matches_ref_all_activations(act):
 
 
 @pytest.mark.parametrize("act", ["swiglu", "relu2", "gelu"])
-@pytest.mark.parametrize("dm_tile,de_tile", [(8, 8), (16, 12), (32, 24)])
+@pytest.mark.parametrize("dm_tile,de_tile", [(128, 128), (128, 256),
+                                              (256, 128)])
 def test_kernel_tiled_matches_ref(act, dm_tile, de_tile):
-    """Micro-slices larger than one VMEM block lower via d_model/m tiling;
-    C=37 with token_tile=16 also exercises padded-row masking."""
-    xe, wg, wu, wd = _shapes()
+    """Micro-slices larger than one VMEM block lower via d_model/m tiling
+    (LANE-aligned tiles, so the widths are multiples of 128); C=37 with
+    token_tile=16 also exercises padded-row masking."""
+    xe, wg, wu, wd = _shapes(E=2, d=256, m=512)
     wg = wg if act == "swiglu" else None
     got = streamed_moe_kernel(xe, wg, wu, wd, activation=act, token_tile=16,
                               dmodel_tile=dm_tile, dexpert_tile=de_tile,
@@ -124,13 +126,51 @@ def test_fse_dp_single_device_kernel_parity():
 def test_kernel_micro_slice_sum_order_invariant():
     """Σ over permuted d_expert micro-slices == whole-expert FFN through the
     new tiled kernel (FSE-DP virtualization property)."""
-    E, C, d, de, M = 2, 19, 32, 48, 4
+    from repro.kernels.streamed_moe import resolve_tiles
+    E, C, d, de, M = 2, 19, 256, 48, 4
     xe, wg, wu, wd = _shapes(E=E, C=C, d=d, m=de, key=7)
     full = ref.streamed_moe_ref(xe, wg, wu, wd, "swiglu")
     mic = de // M
+    # d_model really is tiled (two LANE-wide tiles), not rounded to whole
+    assert resolve_tiles(C, d, mic, gated=True, x_bytes=4, w_bytes=4,
+                         token_tile=8, dmodel_tile=128)[1] == 128
     parts = [streamed_moe_kernel(
         xe, wg[..., i * mic:(i + 1) * mic], wu[..., i * mic:(i + 1) * mic],
         wd[:, i * mic:(i + 1) * mic, :], activation="swiglu", token_tile=8,
-        dmodel_tile=16, interpret=True)
+        dmodel_tile=128, interpret=True)
         for i in np.random.default_rng(0).permutation(M)]
     np.testing.assert_allclose(sum(parts), full, rtol=3e-5, atol=3e-5)
+
+
+# (E, d_model, d_expert) of the MoE configs whose widths the old
+# any-divisor rule and per-block VMEM cap got wrong on v5e
+_REAL_WIDTHS = [(32, 1024, 512),       # granite-moe-1b-a400m
+                (128, 2048, 768),      # qwen3-30b-a3b
+                (64, 2048, 1408)]      # deepseek-moe-16b
+
+
+@pytest.mark.parametrize("dim,req,want", [
+    (1408, 1024, 128),    # 704 divides 1408 but is not LANE-aligned
+    (768, 512, 384), (768, 768, 768), (2048, 1000, 512),
+    (1408, 64, 128),      # below the smallest aligned tile: round up
+    (24, 8, 24),          # no LANE-aligned divisor: the full dim
+])
+def test_fit_tile_is_lane_aligned_or_full(dim, req, want):
+    from repro.kernels.streamed_moe import fit_tile
+    assert fit_tile(dim, req) == want
+
+
+@pytest.mark.parametrize("E,d,m", _REAL_WIDTHS)
+@pytest.mark.parametrize("C", [1, 8, 64, 4096])
+@pytest.mark.parametrize("w_bytes", [2, 1])
+def test_default_tiles_fit_the_vmem_budget(E, d, m, C, w_bytes):
+    """Every default tile obeys the Mosaic block rule and the whole
+    working set (double-buffered blocks, output, scratch) fits the
+    scoped-VMEM budget."""
+    from repro.kernels.streamed_moe import (LANE, SUBLANE, VMEM_BUDGET_BYTES,
+                                            default_tiles, vmem_bytes)
+    Tc, Tj, Tk = default_tiles(C, d, m, gated=True, w_bytes=w_bytes)
+    assert Tc == C or Tc % SUBLANE == 0
+    for t, dim in ((Tj, d), (Tk, m)):
+        assert dim % t == 0 and (t % LANE == 0 or t == dim)
+    assert vmem_bytes(Tc, Tj, Tk, True, 2, w_bytes) <= VMEM_BUDGET_BYTES
