@@ -96,6 +96,7 @@ def flash_attention_kernel(q, k, v, *, q_tile=128, k_tile=128,
             pltpu.VMEM((tq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     out = out[:, :Sq].reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
     return out

@@ -277,5 +277,6 @@ def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
         out_shape=jax.ShapeDtypeStruct((E, Cp, d), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="streamed_moe",
     )(*operands)
     return out[:, :C]

@@ -328,13 +328,14 @@ def moe_block(params, x, moe: MoEConfig, activation, *, impl=None, spec=None,
     shape = x.shape
     if x.ndim == 2:
         x = x[None]
-    with sp.scope():
+    with sp.scope(), jax.named_scope("expert_ffn"):
         y, aux = strat.get_strategy(name).execute(params, x, moe, activation,
                                                   axis=mesh_axis,
                                                   routing=routing,
                                                   schedule=schedule)
     if moe.num_shared_experts:
-        y = y + ffn(params["shared"], x, activation)
+        with jax.named_scope("shared_experts"):
+            y = y + ffn(params["shared"], x, activation)
     y = y.reshape(shape)
     if return_aux:
         return y, aux

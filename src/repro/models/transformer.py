@@ -394,28 +394,29 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
         new_caches = []
         counts = []
         for s, (mixer, ffn_kind) in enumerate(plan):
-            h = apply_norm(cfg.norm, period_params[s]["norm1"], x)
-            if mixer == "attn":
-                if page_table is not None:
-                    h, kv = attn_mod.attention_append_paged(
-                        period_params[s]["attn"], h, period_caches[s].kv,
-                        page_table, cache_len, n_heads=cfg.num_heads,
-                        n_kv=cfg.num_kv_heads,
-                        head_dim=cfg.resolved_head_dim,
-                        rope_theta=cfg.rope_theta, token_mask=token_mask)
+            with jax.named_scope(mixer):
+                h = apply_norm(cfg.norm, period_params[s]["norm1"], x)
+                if mixer == "attn":
+                    if page_table is not None:
+                        h, kv = attn_mod.attention_append_paged(
+                            period_params[s]["attn"], h, period_caches[s].kv,
+                            page_table, cache_len, n_heads=cfg.num_heads,
+                            n_kv=cfg.num_kv_heads,
+                            head_dim=cfg.resolved_head_dim,
+                            rope_theta=cfg.rope_theta, token_mask=token_mask)
+                    else:
+                        h, kv = attn_mod.attention_append(
+                            period_params[s]["attn"], h, period_caches[s].kv,
+                            cache_len, n_heads=cfg.num_heads,
+                            n_kv=cfg.num_kv_heads,
+                            head_dim=cfg.resolved_head_dim,
+                            rope_theta=cfg.rope_theta, token_mask=token_mask)
+                    new_caches.append(SlotCache(kv, period_caches[s].ssm))
                 else:
-                    h, kv = attn_mod.attention_append(
-                        period_params[s]["attn"], h, period_caches[s].kv,
-                        cache_len, n_heads=cfg.num_heads,
-                        n_kv=cfg.num_kv_heads,
-                        head_dim=cfg.resolved_head_dim,
-                        rope_theta=cfg.rope_theta, token_mask=token_mask)
-                new_caches.append(SlotCache(kv, period_caches[s].ssm))
-            else:
-                h, st = ssm_mod.mamba2_chunk(
-                    period_params[s]["ssm"], h, period_caches[s].ssm,
-                    cfg.ssm, cfg.d_model, token_mask=token_mask)
-                new_caches.append(SlotCache(period_caches[s].kv, st))
+                    h, st = ssm_mod.mamba2_chunk(
+                        period_params[s]["ssm"], h, period_caches[s].ssm,
+                        cfg.ssm, cfg.d_model, token_mask=token_mask)
+                    new_caches.append(SlotCache(period_caches[s].kv, st))
             x = x + h
             cnt = jnp.zeros((E,), jnp.int32)
             if ffn_kind != "none":
@@ -426,11 +427,14 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
                     if meshctx.get_mesh() is None:
                         # route ONCE: the same Routing feeds the trace
                         # counts and the expert execution
-                        routing = gating.route(
-                            period_params[s]["moe"]["router"],
-                            h.reshape(-1, h.shape[-1]), top_k=cfg.moe.top_k)
-                        cnt = gating.expert_token_counts(
-                            routing, token_mask.reshape(-1)).astype(jnp.int32)
+                        with jax.named_scope("route"):
+                            routing = gating.route(
+                                period_params[s]["moe"]["router"],
+                                h.reshape(-1, h.shape[-1]),
+                                top_k=cfg.moe.top_k)
+                            cnt = gating.expert_token_counts(
+                                routing, token_mask.reshape(-1)
+                            ).astype(jnp.int32)
                     h = moe_mod.moe_block(period_params[s]["moe"], h, cfg.moe,
                                           cfg.activation, spec=sp,
                                           phase="prefill", layer=layer,
@@ -453,10 +457,11 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
     else:
         x, (new_caches, counts) = jax.lax.scan(
             period_body, x, (params["periods"], caches))
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    if return_hidden:
-        return x, new_caches, counts
-    return _unembed(params, x, cfg), new_caches, counts
+    with jax.named_scope("head"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        if return_hidden:
+            return x, new_caches, counts
+        return _unembed(params, x, cfg), new_caches, counts
 
 
 # ---------------------------------------------------------------------------
@@ -516,46 +521,47 @@ def decode_mixer(params, x, caches, cache_len, cfg: ModelConfig,
     mixer, _ = plan[layer % p]
     period_idx, slot_i = divmod(layer, p)
     slot = _layer_slot(params, layer, p)
-    mask = jnp.asarray(mask)
-    h = apply_norm(cfg.norm, slot["norm1"], x)
-    if mixer == "attn" and page_table is not None:
-        pages = jax.tree.map(lambda a: a[period_idx], caches[slot_i].kv)
-        h, new_pages = attn_mod.attention_decode_paged(
-            slot["attn"], h, pages, page_table, cache_len,
-            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-            row_mask=mask)
-        new_stack = jax.tree.map(lambda st, n: st.at[period_idx].set(n),
-                                 caches[slot_i].kv, new_pages)
-        caches = tuple(c if i != slot_i else SlotCache(new_stack, c.ssm)
-                       for i, c in enumerate(caches))
+    with jax.named_scope(mixer):
+        mask = jnp.asarray(mask)
+        h = apply_norm(cfg.norm, slot["norm1"], x)
+        if mixer == "attn" and page_table is not None:
+            pages = jax.tree.map(lambda a: a[period_idx], caches[slot_i].kv)
+            h, new_pages = attn_mod.attention_decode_paged(
+                slot["attn"], h, pages, page_table, cache_len,
+                n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
+                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+                row_mask=mask)
+            new_stack = jax.tree.map(lambda st, n: st.at[period_idx].set(n),
+                                     caches[slot_i].kv, new_pages)
+            caches = tuple(c if i != slot_i else SlotCache(new_stack, c.ssm)
+                           for i, c in enumerate(caches))
+            return jnp.where(mask[:, None, None], x + h, x), caches
+        cache = jax.tree.map(lambda a: a[period_idx], caches[slot_i])
+        if mixer == "attn":
+            h, new_kv = attn_mod.attention_decode(
+                slot["attn"], h, cache.kv, cache_len,
+                n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
+                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
+            new_cache = SlotCache(new_kv, cache.ssm)
+        else:
+            h, new_state = ssm_mod.mamba2_decode(slot["ssm"], h, cache.ssm,
+                                                 cfg.ssm, cfg.d_model)
+            new_cache = SlotCache(cache.kv, new_state)
+
+        # masked cache update (only active slots advance)
+        def upd(old_stack, old, new):
+            if not hasattr(new, "ndim") or new.ndim == 0:
+                return old_stack
+            m = mask.reshape((-1,) + (1,) * (new.ndim - 1))
+            merged = jnp.where(m, new, old)
+            return old_stack.at[period_idx].set(merged)
+
+        caches = tuple(
+            c if i != slot_i else jax.tree.map(
+                lambda stack, o, n: upd(stack, o, n), caches[slot_i], cache,
+                new_cache)
+            for i, c in enumerate(caches))
         return jnp.where(mask[:, None, None], x + h, x), caches
-    cache = jax.tree.map(lambda a: a[period_idx], caches[slot_i])
-    if mixer == "attn":
-        h, new_kv = attn_mod.attention_decode(
-            slot["attn"], h, cache.kv, cache_len,
-            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
-        new_cache = SlotCache(new_kv, cache.ssm)
-    else:
-        h, new_state = ssm_mod.mamba2_decode(slot["ssm"], h, cache.ssm,
-                                             cfg.ssm, cfg.d_model)
-        new_cache = SlotCache(cache.kv, new_state)
-
-    # masked cache update (only active slots advance)
-    def upd(old_stack, old, new):
-        if not hasattr(new, "ndim") or new.ndim == 0:
-            return old_stack
-        m = mask.reshape((-1,) + (1,) * (new.ndim - 1))
-        merged = jnp.where(m, new, old)
-        return old_stack.at[period_idx].set(merged)
-
-    caches = tuple(
-        c if i != slot_i else jax.tree.map(
-            lambda stack, o, n: upd(stack, o, n), caches[slot_i], cache,
-            new_cache)
-        for i, c in enumerate(caches))
-    return jnp.where(mask[:, None, None], x + h, x), caches
 
 
 def decode_route(params, x, cfg: ModelConfig, layer: int, count_mask=None):
@@ -568,13 +574,14 @@ def decode_route(params, x, cfg: ModelConfig, layer: int, count_mask=None):
     from repro.core import gating
     p, _ = cached_period_plan(cfg)
     slot = _layer_slot(params, layer, p)
-    h = apply_norm(cfg.norm, slot["norm2"], x)
-    routing = gating.route(slot["moe"]["router"], h[:, 0, :],
-                           top_k=cfg.moe.top_k)
-    counts = None
-    if count_mask is not None:
-        counts = gating.expert_token_counts(routing,
-                                            jnp.asarray(count_mask))
+    with jax.named_scope("route"):
+        h = apply_norm(cfg.norm, slot["norm2"], x)
+        routing = gating.route(slot["moe"]["router"], h[:, 0, :],
+                               top_k=cfg.moe.top_k)
+        counts = None
+        if count_mask is not None:
+            counts = gating.expert_token_counts(routing,
+                                                jnp.asarray(count_mask))
     return h, routing, counts
 
 
@@ -622,8 +629,9 @@ def decode_span(params, x, caches, cache_len, cfg: ModelConfig,
 
 def decode_logits(params, x, cfg: ModelConfig):
     """Final norm + unembed of the carried (B,1,d) residual stream."""
-    h = apply_norm(cfg.norm, params["final_norm"], x)
-    return _unembed(params, h, cfg)
+    with jax.named_scope("head"):
+        h = apply_norm(cfg.norm, params["final_norm"], x)
+        return _unembed(params, h, cfg)
 
 
 def decode_step(params, token, caches, cache_len, cfg: ModelConfig, *,

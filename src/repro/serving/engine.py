@@ -18,8 +18,10 @@ Two execution paths share one set of per-layer entry points
   steady-state decode iteration is ``k + 1`` compiled dispatches with
   at most **one host sync per MoE boundary** (a single
   ``device_get((counts, indices))`` feeding deferral, the workload
-  trace, and the LoadTracker EMA) plus one logits fetch for sampling —
-  counted in ``stats["host_syncs"]`` and pinned by tests;
+  trace, and the LoadTracker EMA) plus one logits fetch for sampling
+  (and a recount at a boundary that defers a row) — every read goes
+  through ``Engine._fetch``, counted in ``stats["host_syncs"]`` and
+  pinned by tests;
 * **legacy** (``ServeConfig(fused=False)``, and the automatic fallback
   under a distributed mesh) — the original eager per-layer Python loop.
 
@@ -63,10 +65,20 @@ chiplet-array seconds of that layer's observed expert flow
 ``last_step_modeled_s``, which the scheduler's modeled clock integrates
 into machine-independent TTFT/TPOT seconds (see docs/benchmarks.md and
 the ``sim.modes.replay_trace`` referee).
+
+Host spans (``jax.profiler.TraceAnnotation``, free unless a profiler
+trace is being captured) split each iteration on the device's clock:
+``engine.step`` > ``engine.pages`` / ``engine.dispatch`` (one per
+jitted call) / ``engine.fetch`` / ``engine.boundary`` /
+``engine.sample``, and ``gc`` around each garbage collection; JAX
+compile events inside a step are counted in ``stats["compiles"]`` (see
+docs/architecture.md, "Spans and counters").
 """
 from __future__ import annotations
 
+import gc
 import itertools
+import threading
 import warnings
 from collections import deque
 from dataclasses import dataclass, field
@@ -75,6 +87,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import autotune, gating, trajectory
@@ -83,6 +96,89 @@ from repro.models import api, transformer
 from repro.serving import megastep, statepool
 
 _ALIAS_WARNED: set = set()
+
+# JAX's compile-path events (jax 0.9.0: ``jax._src.dispatch`` and
+# ``jax._src.compiler``): tracing a function to a jaxpr, lowering the
+# jaxpr to MLIR, the backend compile, and a load from the persistent
+# compile cache (which happens inside the backend-compile event).
+JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_EVENTS = (JAXPR_TRACE, LOWERING, BACKEND_COMPILE, CACHE_LOAD)
+
+
+class CompileCounter:
+    """Count of JAX's compile-path events in this process, and the wall
+    seconds they cover.
+
+    Traces nest (tracing a jitted function traces the jitted functions
+    it calls), so the seconds are the length of the union of the
+    events' time spans, not the sum of their durations.  A span ends
+    after every span nested in it, so the union is kept as a stack of
+    disjoint intervals ordered by end.  One listener serves every engine
+    (JAX's listeners are process-wide): :meth:`Engine.step` reads it
+    before and after an iteration."""
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        self._covered: List[Tuple[float, float]] = []
+        self._lock = threading.Lock()
+
+    def on_duration(self, event: str, duration: float, **_) -> None:
+        if event in COMPILE_EVENTS:
+            with self._lock:
+                self.count += 1
+
+    def on_span(self, event: str, start: float, end: float, **_) -> None:
+        if event not in COMPILE_EVENTS:
+            return
+        with self._lock:
+            cov = self._covered
+            while cov and cov[-1][1] >= start:
+                a, b = cov.pop()
+                self.seconds -= b - a
+                start, end = min(start, a), max(end, b)
+            cov.append((start, end))
+            self.seconds += end - start
+
+    def read(self) -> Tuple[int, float]:
+        with self._lock:
+            return self.count, self.seconds
+
+
+class GcSpan:
+    """A ``gc`` host span around each collection of Python's garbage
+    collector (``generation`` in its args), so a pause shows in a
+    profiler trace under its own name wherever in the loop it lands."""
+
+    def __init__(self):
+        self._span = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._span = TraceAnnotation("gc", generation=info["generation"])
+            self._span.__enter__()
+        elif self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+
+_COMPILES: Optional[CompileCounter] = None
+
+
+def compile_counter() -> CompileCounter:
+    """The process's compile counter.  Its first use registers the
+    process-wide hooks: the counter's JAX listeners and the ``gc`` span."""
+    global _COMPILES
+    if _COMPILES is None:
+        _COMPILES = CompileCounter()
+        jax.monitoring.register_event_duration_secs_listener(
+            _COMPILES.on_duration)
+        jax.monitoring.register_event_time_span_listener(_COMPILES.on_span)
+        gc.callbacks.append(GcSpan())
+    return _COMPILES
 
 
 def _warn_alias(old: str, new: str) -> None:
@@ -263,9 +359,14 @@ class Engine:
                       "iterations": 0, "tokens_emitted": 0,
                       "dynamic_schedules": 0,
                       "prefill_chunks": 0, "prefill_tokens": 0,
-                      # device fetches on the fused path (boundary count
-                      # fetches + logits fetches + prefill count fetches)
+                      # blocking device reads through _fetch: every one
+                      # of the fused path (boundary counts, logits batch,
+                      # prefill counts, first-token rows, deferral
+                      # recounts); the legacy loop's slot recounts
                       "host_syncs": 0,
+                      # JAX trace / lowering / compile / cache-load events
+                      # inside Engine.step, and their seconds
+                      "compiles": 0, "compile_s": 0.0,
                       "preemptions": 0, "restores": 0}
         # state-pool counters (pages in use / peak, cache hit/miss/evict,
         # prefill tokens saved, resident bytes) live in the same dict:
@@ -510,9 +611,9 @@ class Engine:
 
     def _record_event(self, event: str, **fields) -> None:
         """Append one *event* trace record (``cache_hit`` / ``preempt``
-        / ``restore``).  Event records carry no ``counts`` and no
-        modeled seconds — consumers that aggregate expert flow skip
-        them (see docs/trace-format.md)."""
+        / ``restore`` / ``compile``).  Event records carry no ``counts``
+        and no modeled seconds — consumers that aggregate expert flow
+        skip them (see docs/trace-format.md)."""
         self.trace.append({"iter": self.iterations, "event": event,
                            **fields})
 
@@ -523,16 +624,26 @@ class Engine:
         writes its chunk, plus one more when the prompt completes (the
         row joins the decode batch in the same iteration)."""
         K = max(1, self.scfg.chunk_tokens)
-        for r in self.active():
-            if r.phase == "prefill":
-                k_r = min(K, len(r.prompt) - r.prefill_pos)
-                length = int(self.cache_len[r.slot]) + k_r
-                if r.prefill_pos + k_r >= len(r.prompt):
-                    length += 1
-            else:
-                length = int(self.cache_len[r.slot]) + 1
-            self.pool.ensure(r.slot, min(length, self.scfg.max_ctx))
-        self._table_dev = jnp.asarray(self.pool.table)
+        with TraceAnnotation("engine.pages"):
+            for r in self.active():
+                if r.phase == "prefill":
+                    k_r = min(K, len(r.prompt) - r.prefill_pos)
+                    length = int(self.cache_len[r.slot]) + k_r
+                    if r.prefill_pos + k_r >= len(r.prompt):
+                        length += 1
+                else:
+                    length = int(self.cache_len[r.slot]) + 1
+                self.pool.ensure(r.slot, min(length, self.scfg.max_ctx))
+            self._table_dev = jnp.asarray(self.pool.table)
+
+    def _fetch(self, x, site: str, layer: int = -1):
+        """A blocking device-to-host read (every one of the fused path
+        goes through here): counted in ``stats["host_syncs"]`` and
+        spanned as ``engine.fetch`` (the wait for the device plus the
+        transfer)."""
+        self.stats["host_syncs"] += 1
+        with TraceAnnotation("engine.fetch", site=site, layer=layer):
+            return jax.device_get(x)
 
     def _register_prefix(self, r: RequestState) -> None:
         """Cache the slot's state at this chunk boundary under the
@@ -576,11 +687,12 @@ class Engine:
             took[r.rid] = k_r
         if fused:
             ms = megastep.get_megastep(self.cfg, self.scfg)
-            hid, self.caches, counts = ms.prefill(
-                self.params, tokens, self.caches,
-                jnp.asarray(self.cache_len), self._table_dev,
-                jnp.asarray(mask))
-            self.stats["host_syncs"] += 1       # the counts fetch below
+            with TraceAnnotation("engine.dispatch", segment=ms.PREFILL):
+                hid, self.caches, counts = ms.prefill(
+                    self.params, tokens, self.caches,
+                    jnp.asarray(self.cache_len), self._table_dev,
+                    jnp.asarray(mask))
+            counts = self._fetch(counts, "prefill_counts")
         else:
             hid, self.caches, counts = api.prefill_chunk_fn(
                 self.params, jnp.asarray(tokens), self.caches,
@@ -588,63 +700,82 @@ class Engine:
                 token_mask=jnp.asarray(mask), return_hidden=True,
                 page_table=self._table_dev)
         counts = np.asarray(counts, np.int64)
-        for layer in range(self.L):
-            if self._layer_kind(layer)[1] != "moe":
-                continue
-            cnt = counts[layer // self.p, layer % self.p]
-            tracker = self.load_trackers.setdefault(
-                layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
-                                              decay=scfg.ema_decay))
-            tracker.update(cnt)
-            self._record({
-                "iter": self.iterations, "layer": layer, "phase": "prefill",
-                "counts": cnt.copy(), "order": paired_load_order(cnt),
-                "schedule": "dynamic" if self.dynamic_schedule else "static"})
-            self.stats["expert_loads"] += int((cnt > 0).sum())
+        with TraceAnnotation("engine.boundary"):
+            for layer in range(self.L):
+                if self._layer_kind(layer)[1] != "moe":
+                    continue
+                cnt = counts[layer // self.p, layer % self.p]
+                tracker = self.load_trackers.setdefault(
+                    layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
+                                                  decay=scfg.ema_decay))
+                tracker.update(cnt)
+                self._record({
+                    "iter": self.iterations, "layer": layer,
+                    "phase": "prefill", "counts": cnt.copy(),
+                    "order": paired_load_order(cnt),
+                    "schedule": ("dynamic" if self.dynamic_schedule
+                                 else "static")})
+                self.stats["expert_loads"] += int((cnt > 0).sum())
 
         out: List[Tuple[str, int]] = []
         head = self.params.get("lm_head")
         head = head if head is not None else self.params["embed"].T
-        for r in pre:
-            k_r = took[r.rid]
-            self.cache_len[r.slot] += k_r
-            r.prefill_pos += k_r
-            self.stats["prefill_tokens"] += k_r
-            if scfg.prefix_cache and r.prefix_keys:
-                self._register_prefix(r)
-            if r.prefill_pos < len(r.prompt):
-                continue
-            # prompt fully cached: unembed just this row's final chunk
-            # position, emit the first token, and join decode
-            first = self._sample_row(r, hid[r.slot, k_r - 1] @ head)
-            r.generated.append(int(first))
-            r.phase = "decode"
-            r.progress = 0
-            r.prompt = []
-            out.append((r.rid, int(first)))
-            self.stats["tokens_emitted"] += 1
-            if len(r.generated) >= r.max_new:
-                r.done = True
-                self.free_slots.append(r.slot)
-                self.pool.release_slot(r.slot)
-                self.policy.drop(r.rid)
+        with TraceAnnotation("engine.sample"):
+            for r in pre:
+                k_r = took[r.rid]
+                self.cache_len[r.slot] += k_r
+                r.prefill_pos += k_r
+                self.stats["prefill_tokens"] += k_r
+                if scfg.prefix_cache and r.prefix_keys:
+                    self._register_prefix(r)
+                if r.prefill_pos < len(r.prompt):
+                    continue
+                # prompt fully cached: unembed just this row's final
+                # chunk position, emit the first token, and join decode
+                row = hid[r.slot, k_r - 1] @ head
+                if fused:
+                    row = self._fetch(row, "first_token")
+                first = self._sample_row(r, row)
+                r.generated.append(int(first))
+                r.phase = "decode"
+                r.progress = 0
+                r.prompt = []
+                out.append((r.rid, int(first)))
+                self.stats["tokens_emitted"] += 1
+                if len(r.generated) >= r.max_new:
+                    r.done = True
+                    self.free_slots.append(r.slot)
+                    self.pool.release_slot(r.slot)
+                    self.policy.drop(r.rid)
         self.stats["prefill_chunks"] += len(pre)
         return out
 
     def step(self) -> List[Tuple[str, int]]:
+        """One iteration, spanned as ``engine.step``.  JAX compile-path
+        events inside it add to ``stats["compiles"]`` / ``["compile_s"]``
+        and leave a ``compile`` event record in the trace."""
         self.last_step_modeled_s = 0.0
         if not self.active():
             return []
-        self._iter_modeled_s = 0.0
-        # allocate pages for this iteration's KV writes and push the
-        # table once; it enters every jitted segment as a traced array
-        self._ensure_pages()
-        from repro.parallel import meshctx
-        if self.scfg.fused and meshctx.get_mesh() is None:
-            out = self._step_fused()
-        else:
-            out = self._step_legacy()
-        self.last_step_modeled_s = self._iter_modeled_s
+        with StepTraceAnnotation("engine.step",
+                                 step_num=self.iterations + 1):
+            n0, s0 = compile_counter().read()
+            self._iter_modeled_s = 0.0
+            # allocate pages for this iteration's KV writes and push the
+            # table once; it enters every jitted segment as a traced array
+            self._ensure_pages()
+            from repro.parallel import meshctx
+            if self.scfg.fused and meshctx.get_mesh() is None:
+                out = self._step_fused()
+            else:
+                out = self._step_legacy()
+            self.last_step_modeled_s = self._iter_modeled_s
+            n1, s1 = compile_counter().read()
+            if n1 > n0:
+                self.stats["compiles"] += n1 - n0
+                self.stats["compile_s"] += s1 - s0
+                self._record_event("compile", count=n1 - n0,
+                                   seconds=s1 - s0)
         return out
 
     # ------------------------------------------------------------------
@@ -672,13 +803,14 @@ class Engine:
 
         ms = megastep.get_megastep(self.cfg, self.scfg)
         token_vec, start_mask = self._start_masks(act)
-        cl = jnp.asarray(self.cache_len)
         bnds = ms.boundaries
 
         if not bnds:
-            self._x, self.caches, logits = ms.seg_only(
-                self.params, self._x, self.caches, cl, self._table_dev,
-                token_vec, start_mask)
+            with TraceAnnotation("engine.dispatch", segment=ms.ONLY):
+                self._x, self.caches, logits = ms.seg_only(
+                    self.params, self._x, self.caches,
+                    jnp.asarray(self.cache_len), self._table_dev,
+                    token_vec, start_mask)
             for r in act:
                 if start_mask[r.slot]:
                     r.progress = 2 * self.L
@@ -690,27 +822,31 @@ class Engine:
             if r.progress == 0:
                 r.progress = 2 * b0 + 1
         run_ffn = [r for r in act if not r.done and r.progress == 2 * b0 + 1]
-        self._x, self.caches, h, routing, counts = ms.seg_first(
-            self.params, self._x, self.caches, cl, self._table_dev,
-            token_vec, start_mask, self._mask([r.slot for r in run_ffn]))
+        with TraceAnnotation("engine.dispatch", segment=ms.FIRST):
+            cl = jnp.asarray(self.cache_len)
+            self._x, self.caches, h, routing, counts = ms.seg_first(
+                self.params, self._x, self.caches, cl, self._table_dev,
+                token_vec, start_mask, self._mask([r.slot for r in run_ffn]))
         kept, order = self._boundary_fused(b0, run_ffn, routing, counts, ms)
 
         for j, b in enumerate(bnds[1:], start=1):
-            exec_mask = self._mask([r.slot for r in kept])
             for r in kept:
                 r.progress = 2 * b + 1
             run_ffn = [r for r in act
                        if not r.done and r.progress == 2 * b + 1]
-            self._x, self.caches, h, routing, counts = ms.seg_mid[j - 1](
-                self.params, self._x, self.caches, cl, self._table_dev,
-                h, routing, order, exec_mask,
-                self._mask([r.slot for r in run_ffn]))
+            with TraceAnnotation("engine.dispatch",
+                                 segment=ms.mid_names[j - 1]):
+                self._x, self.caches, h, routing, counts = ms.seg_mid[j - 1](
+                    self.params, self._x, self.caches, cl, self._table_dev,
+                    h, routing, order, self._mask([r.slot for r in kept]),
+                    self._mask([r.slot for r in run_ffn]))
             kept, order = self._boundary_fused(b, run_ffn, routing, counts,
                                                ms)
 
-        self._x, self.caches, logits = ms.seg_last(
-            self.params, self._x, self.caches, cl, self._table_dev,
-            h, routing, order, self._mask([r.slot for r in kept]))
+        with TraceAnnotation("engine.dispatch", segment=ms.LAST):
+            self._x, self.caches, logits = ms.seg_last(
+                self.params, self._x, self.caches, cl, self._table_dev,
+                h, routing, order, self._mask([r.slot for r in kept]))
         for r in kept:
             r.progress = 2 * self.L
         return self._finish(act, logits, out, fetch=True)
@@ -724,11 +860,11 @@ class Engine:
             # nobody reaches this boundary: no fetch, no record, no EMA
             # (matches the legacy loop's `if not run_ffn: continue`)
             return [], ms.identity_order
-        self.stats["host_syncs"] += 1
         if self.policy.n_threshold < _DEFER_OFF:
-            counts_np, idx = jax.device_get((counts_dev, routing.indices))
+            counts_np, idx = self._fetch((counts_dev, routing.indices),
+                                         "boundary", layer)
         else:
-            counts_np, idx = jax.device_get(counts_dev), None
+            counts_np, idx = self._fetch(counts_dev, "boundary", layer), None
         kept = self._boundary_host(layer, run_ffn,
                                    np.asarray(counts_np, np.int64), idx,
                                    routing)
@@ -746,23 +882,23 @@ class Engine:
         finish = [r for r in act if not r.done and r.progress == 2 * self.L]
         if not finish:
             return out
-        if fetch:
-            self.stats["host_syncs"] += 1
-            logits = jax.device_get(logits)
-        for r in finish:
-            tok = self._sample_row(r, logits[r.slot, 0])
-            r.generated.append(tok)
-            out.append((r.rid, tok))
-            self.stats["tokens_emitted"] += 1
-            r.progress = 0
-            self.cache_len[r.slot] += 1
-            self.policy.on_forward_pass(r.rid)
-            if len(r.generated) >= r.max_new or \
-                    int(self.cache_len[r.slot]) >= scfg.max_ctx - 1:
-                r.done = True
-                self.free_slots.append(r.slot)
-                self.pool.release_slot(r.slot)
-                self.policy.drop(r.rid)
+        with TraceAnnotation("engine.sample"):
+            if fetch:
+                logits = self._fetch(logits, "logits")
+            for r in finish:
+                tok = self._sample_row(r, logits[r.slot, 0])
+                r.generated.append(tok)
+                out.append((r.rid, tok))
+                self.stats["tokens_emitted"] += 1
+                r.progress = 0
+                self.cache_len[r.slot] += 1
+                self.policy.on_forward_pass(r.rid)
+                if len(r.generated) >= r.max_new or \
+                        int(self.cache_len[r.slot]) >= scfg.max_ctx - 1:
+                    r.done = True
+                    self.free_slots.append(r.slot)
+                    self.pool.release_slot(r.slot)
+                    self.policy.drop(r.rid)
         return out
 
     # ------------------------------------------------------------------
@@ -832,11 +968,12 @@ class Engine:
             page_table=self._table_dev)
         return x
 
-    def _slot_counts(self, routing, slots):
+    def _slot_counts(self, routing, slots, layer):
         """Expert counts restricted to the given slots
-        (``gating.expert_token_counts`` with a row mask)."""
-        return np.asarray(gating.expert_token_counts(
-            routing, self._mask(slots)), np.int64)
+        (``gating.expert_token_counts`` with a row mask), read through
+        :meth:`_fetch`."""
+        return np.asarray(self._fetch(gating.expert_token_counts(
+            routing, self._mask(slots)), "slot_counts", layer), np.int64)
 
     def _boundary_host(self, layer, run_ffn, counts, idx, routing):
         """Shared host bookkeeping at one MoE boundary (both paths):
@@ -845,51 +982,54 @@ class Engine:
         deferral sweep.  ``counts`` are this boundary's observed expert
         counts (np.int64), ``idx`` the per-row routed expert ids (None
         when deferral is off).  Returns the non-deferred rows."""
-        tracker = self.load_trackers.setdefault(
-            layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
-                                          decay=self.scfg.ema_decay))
-        tracker.update(counts)
-        rec = {"iter": self.iterations, "layer": layer, "phase": "decode",
-               "counts": counts.copy(),
-               "order": paired_load_order(counts),
-               "schedule": "dynamic" if self.dynamic_schedule else "static"}
-        if self.dynamic_schedule:
-            # build the EMA schedule once; the expert execution that
-            # follows (next segment / _apply_moe) runs along it.  Under
-            # the hybrid strategy the plan carries the engine's fast-tier
-            # width so the executed partition matches the trace's ``hot``
-            plan = None
-            if self._n_hot:
-                plan = autotune.Plan(mode="hybrid", family="hybrid",
-                                     micro_slices=1,
-                                     hot_experts=self._n_hot)
-            sched = tracker.schedule(plan=plan)
-            self._layer_schedules[layer] = sched
-            rec["trajectory"] = list(sched.order)
-        self._record(rec)
-        self.stats["expert_loads"] += int((counts > 0).sum())
-        if self.policy.n_threshold >= _DEFER_OFF:
-            return list(run_ffn)
-        kept = []
-        for r in run_ffn:
-            acts = [int(e) for e in idx[r.slot]]
-            if self.policy.should_defer(r.rid, acts, counts):
-                self.stats["deferrals"] += 1
-                r.deferred_iterations += 1
-            else:
-                kept.append(r)
-        if len(kept) != len(run_ffn):
-            counts2 = self._slot_counts(routing, [r.slot for r in kept])
-            self.stats["expert_loads_saved"] += int((counts > 0).sum()
-                                                    - (counts2 > 0).sum())
-        return kept
+        with TraceAnnotation("engine.boundary"):
+            tracker = self.load_trackers.setdefault(
+                layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
+                                              decay=self.scfg.ema_decay))
+            tracker.update(counts)
+            rec = {"iter": self.iterations, "layer": layer, "phase": "decode",
+                   "counts": counts.copy(),
+                   "order": paired_load_order(counts),
+                   "schedule": ("dynamic" if self.dynamic_schedule
+                                else "static")}
+            if self.dynamic_schedule:
+                # build the EMA schedule once; the expert execution that
+                # follows (next segment / _apply_moe) runs along it.  Under
+                # the hybrid strategy the plan carries the engine's fast-tier
+                # width so the executed partition matches the trace's ``hot``
+                plan = None
+                if self._n_hot:
+                    plan = autotune.Plan(mode="hybrid", family="hybrid",
+                                         micro_slices=1,
+                                         hot_experts=self._n_hot)
+                sched = tracker.schedule(plan=plan)
+                self._layer_schedules[layer] = sched
+                rec["trajectory"] = list(sched.order)
+            self._record(rec)
+            self.stats["expert_loads"] += int((counts > 0).sum())
+            if self.policy.n_threshold >= _DEFER_OFF:
+                return list(run_ffn)
+            kept = []
+            for r in run_ffn:
+                acts = [int(e) for e in idx[r.slot]]
+                if self.policy.should_defer(r.rid, acts, counts):
+                    self.stats["deferrals"] += 1
+                    r.deferred_iterations += 1
+                else:
+                    kept.append(r)
+            if len(kept) != len(run_ffn):
+                counts2 = self._slot_counts(routing,
+                                            [r.slot for r in kept], layer)
+                self.stats["expert_loads_saved"] += int((counts > 0).sum()
+                                                        - (counts2 > 0).sum())
+            return kept
 
     def _defer_cold(self, routing, layer, run_ffn):
         """Algorithm 2 at the MoE boundary (legacy eager path); returns
         the non-deferred set.  Also the *schedule* stage's observation
         point: the counts feed the layer's LoadTracker EMA and the
         exported workload trace."""
-        counts = self._slot_counts(routing, [r.slot for r in run_ffn])
+        counts = self._slot_counts(routing, [r.slot for r in run_ffn], layer)
         idx = None
         if self.policy.n_threshold < _DEFER_OFF:
             idx = np.asarray(routing.indices)          # (B, k)

@@ -36,6 +36,11 @@ boolean masks and the dynamic trajectory enters as a traced ``(E,)``
 order array, so steady-state decode (and deferral/finish churn) never
 retraces: ``MegaStep.traces`` counts trace events and the test suite
 pins it flat after warmup.
+
+Each jitted program has a stable name, which names its module on the
+device and the engine's ``engine.dispatch`` span: ``prefill_chunk``,
+``decode_seg_first``, ``decode_seg_mid_<b>`` (ending at boundary ``b``),
+``decode_seg_last`` and ``decode_seg_only``.
 """
 from __future__ import annotations
 
@@ -59,6 +64,11 @@ class MegaStep:
     with kernels on must never be reused with kernels off.
     """
 
+    PREFILL = "prefill_chunk"
+    FIRST = "decode_seg_first"
+    LAST = "decode_seg_last"
+    ONLY = "decode_seg_only"
+
     def __init__(self, cfg, spec, *, max_batch: int, max_ctx: int,
                  chunk_tokens: int):
         self.cfg = cfg
@@ -76,6 +86,7 @@ class MegaStep:
         # once (Python side effect in the traced body) — the recompile
         # guard in tests/test_megastep.py reads it
         self.traces = 0
+        self.mid_names = [f"decode_seg_mid_{b}" for b in self.boundaries[1:]]
         self._build()
 
     # ------------------------------------------------------------------
@@ -103,7 +114,7 @@ class MegaStep:
                 params, tokens, caches, cache_len, cfg, spec=spec,
                 token_mask=token_mask, return_hidden=True, page_table=table)
 
-        self.prefill = jax.jit(prefill, donate_argnums=(2,))
+        self.prefill = _jit(prefill, self.PREFILL, donate_argnums=(2,))
 
         if not bnds:
             def only(params, x, caches, cache_len, table, token_vec,
@@ -117,7 +128,7 @@ class MegaStep:
                                                     page_table=table)
                 return x, caches, transformer.decode_logits(params, x, cfg)
 
-            self.seg_only = jax.jit(only, donate_argnums=(1, 2))
+            self.seg_only = _jit(only, self.ONLY, donate_argnums=(1, 2))
             self.seg_first = self.seg_mid = self.seg_last = None
             return
 
@@ -138,9 +149,9 @@ class MegaStep:
                                                           count_mask)
             return x, caches, h, routing, counts
 
-        self.seg_first = jax.jit(first, donate_argnums=(1, 2))
+        self.seg_first = _jit(first, self.FIRST, donate_argnums=(1, 2))
 
-        def make_mid(b_prev: int, b: int):
+        def make_mid(b_prev: int, b: int, name: str):
             def mid(params, x, caches, cache_len, table, h, routing, order,
                     exec_mask, count_mask):
                 self.traces += 1
@@ -156,9 +167,9 @@ class MegaStep:
                 h, routing, counts = transformer.decode_route(params, x, cfg,
                                                               b, count_mask)
                 return x, caches, h, routing, counts
-            return jax.jit(mid, donate_argnums=(1, 2))
+            return _jit(mid, name, donate_argnums=(1, 2))
 
-        self.seg_mid = [make_mid(bnds[j - 1], bnds[j])
+        self.seg_mid = [make_mid(bnds[j - 1], bnds[j], self.mid_names[j - 1])
                         for j in range(1, len(bnds))]
 
         b_tail = bnds[-1]
@@ -174,8 +185,14 @@ class MegaStep:
                                                 page_table=table)
             return x, caches, transformer.decode_logits(params, x, cfg)
 
-        self.seg_last = jax.jit(last, donate_argnums=(1, 2))
+        self.seg_last = _jit(last, self.LAST, donate_argnums=(1, 2))
         self.seg_only = None
+
+
+def _jit(fn, name: str, **kw):
+    """``jax.jit`` of ``fn`` under a stable program name."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
 
 
 _CACHE: dict = {}

@@ -52,6 +52,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from .engine import Engine, QueueFullError
@@ -295,10 +296,15 @@ class Scheduler:
         ``dt`` advances the metric clock (wall seconds in real serving;
         the default 1.0 makes all latency metrics iteration-counted and
         fully deterministic).  Returns (rid, token) pairs in scheduler
-        rids."""
+        rids.  Spanned as ``sched.step``, admission as ``sched.admit``."""
+        with jax.profiler.TraceAnnotation("sched.step"):
+            return self._step(dt)
+
+    def _step(self, dt: float) -> List[Tuple[str, int]]:
         self.iteration += 1
-        self._maybe_preempt()
-        self.admit_ready()
+        with jax.profiler.TraceAnnotation("sched.admit"):
+            self._maybe_preempt()
+            self.admit_ready()
         events = self.engine.step()
         adv = getattr(self.engine, "last_step_modeled_s", 0.0)
         self.modeled_now += adv

@@ -39,7 +39,8 @@ def test_train_serve_simulate_pipeline():
     import dataclasses
     spec = dataclasses.replace(spec_from_config(cfg), d_model=1024, d_expert=512)
     hw = PROTOTYPE_2X2
-    counts_trace = [t["counts"] for t in eng.trace if t["counts"].sum() > 0][:4]
+    counts_trace = [t["counts"] for t in eng.trace
+                    if "counts" in t and t["counts"].sum() > 0][:4]
     assert counts_trace
     ratios = []
     for counts in counts_trace:
