@@ -15,13 +15,19 @@ Two execution paths share one set of per-layer entry points
 
 * **fused** (default) — everything between MoE boundaries runs as one
   donated-buffer jitted mega-step (``repro.serving.megastep``): a
-  steady-state decode iteration is ``k + 1`` compiled dispatches with
-  at most **one host sync per MoE boundary** (a single
-  ``device_get((counts, indices))`` feeding deferral, the workload
-  trace, and the LoadTracker EMA) plus one logits fetch for sampling
-  (and a recount at a boundary that defers a row) — every read goes
-  through ``Engine._fetch``, counted in ``stats["host_syncs"]`` and
-  pinned by tests;
+  steady-state decode iteration is ``k + 1`` compiled dispatches.  When
+  no boundary decision needs host values (deferral off, static
+  schedule) the pass is **sync-free**: the segments are dispatched back
+  to back on device-resident masks, and every boundary's counts (and
+  the prefill chunk's) are read with the logits batch in **one**
+  fetch, after which the host bookkeeping runs in boundary order
+  (``stats["sync_free_passes"]``).  With Algorithm-2 deferral or a
+  dynamic schedule the host decides at each boundary: **one host sync
+  per MoE boundary** (a single ``device_get((counts, indices))``
+  feeding deferral, the workload trace, and the LoadTracker EMA) plus
+  one logits fetch for sampling (and a recount at a boundary that
+  defers a row).  Every read goes through ``Engine._fetch``, counted
+  in ``stats["host_syncs"]`` and pinned by tests;
 * **legacy** (``ServeConfig(fused=False)``, and the automatic fallback
   under a distributed mesh) — the original eager per-layer Python loop.
 
@@ -224,7 +230,8 @@ class ServeConfig:
     # Set False for the paper-faithful finite-buffer EP semantics.
     drop_free: bool = True
     # fused mega-step iteration (repro.serving.megastep): one compiled
-    # segment per MoE-boundary span, at most one host sync per boundary.
+    # segment per MoE-boundary span, at most one host sync per boundary
+    # (one per iteration when no boundary needs a host decision).
     # False keeps the eager per-layer loop (bit-identical, much slower);
     # a distributed mesh falls back to the legacy loop automatically.
     fused: bool = True
@@ -360,10 +367,13 @@ class Engine:
                       "dynamic_schedules": 0,
                       "prefill_chunks": 0, "prefill_tokens": 0,
                       # blocking device reads through _fetch: every one
-                      # of the fused path (boundary counts, logits batch,
-                      # prefill counts, first-token rows, deferral
-                      # recounts); the legacy loop's slot recounts
+                      # of the fused path (the sync-free pass's one read,
+                      # or boundary counts, logits batch, prefill counts;
+                      # first-token rows, deferral recounts); the legacy
+                      # loop's slot recounts
                       "host_syncs": 0,
+                      # fused decode passes that ran sync-free
+                      "sync_free_passes": 0,
                       # JAX trace / lowering / compile / cache-load events
                       # inside Engine.step, and their seconds
                       "compiles": 0, "compile_s": 0.0,
@@ -417,6 +427,9 @@ class Engine:
         self.stats["hybrid_repartitions"] = 0
         self.last_step_modeled_s = 0.0
         self._iter_modeled_s = 0.0
+        # the sync-free pass's row mask on the device, and its slots
+        self._pass_slots: Optional[Tuple[int, ...]] = None
+        self._pass_mask_dev = None
 
     # ------------------------------------------------------------------
     # slot/param helpers
@@ -662,7 +675,9 @@ class Engine:
         if copy is not None:
             self.caches = statepool.copy_page(self.caches, *copy)
 
-    def _prefill_chunk_step(self, fused: bool = False) -> List[Tuple[str, int]]:
+    def _prefill_chunk_step(self, fused: bool = False,
+                            pending: Optional[list] = None
+                            ) -> List[Tuple[str, int]]:
         """Advance every prefilling request by one prompt chunk.
 
         One batched ``prefill_chunk`` call covers all prefilling slots
@@ -671,7 +686,9 @@ class Engine:
         workload trace and the LoadTracker EMAs exactly like the decode
         path's route stage.  Requests whose prompt completes sample
         their first token from the last valid chunk position — the
-        emission the scheduler timestamps as TTFT."""
+        emission the scheduler timestamps as TTFT.  Given ``pending``
+        (the sync-free pass), the counts are not read here: they join
+        the pass's one read as ``(None, (), counts)``."""
         pre = self.prefilling()
         if not pre:
             return []
@@ -688,34 +705,24 @@ class Engine:
         if fused:
             ms = megastep.get_megastep(self.cfg, self.scfg)
             with TraceAnnotation("engine.dispatch", segment=ms.PREFILL):
+                # a copy: the loop below bumps cache_len in place while
+                # the program may still wait to read it
                 hid, self.caches, counts = ms.prefill(
                     self.params, tokens, self.caches,
-                    jnp.asarray(self.cache_len), self._table_dev,
+                    jnp.asarray(self.cache_len.copy()), self._table_dev,
                     jnp.asarray(mask))
-            counts = self._fetch(counts, "prefill_counts")
+            if pending is None:
+                counts = self._fetch(counts, "prefill_counts")
         else:
             hid, self.caches, counts = api.prefill_chunk_fn(
                 self.params, jnp.asarray(tokens), self.caches,
                 jnp.asarray(self.cache_len), self.cfg, spec=scfg.spec,
                 token_mask=jnp.asarray(mask), return_hidden=True,
                 page_table=self._table_dev)
-        counts = np.asarray(counts, np.int64)
-        with TraceAnnotation("engine.boundary"):
-            for layer in range(self.L):
-                if self._layer_kind(layer)[1] != "moe":
-                    continue
-                cnt = counts[layer // self.p, layer % self.p]
-                tracker = self.load_trackers.setdefault(
-                    layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
-                                                  decay=scfg.ema_decay))
-                tracker.update(cnt)
-                self._record({
-                    "iter": self.iterations, "layer": layer,
-                    "phase": "prefill", "counts": cnt.copy(),
-                    "order": paired_load_order(cnt),
-                    "schedule": ("dynamic" if self.dynamic_schedule
-                                 else "static")})
-                self.stats["expert_loads"] += int((cnt > 0).sum())
+        if pending is None:
+            self._prefill_records(np.asarray(counts, np.int64))
+        else:
+            pending.append((None, (), counts))
 
         out: List[Tuple[str, int]] = []
         head = self.params.get("lm_head")
@@ -749,6 +756,27 @@ class Engine:
                     self.policy.drop(r.rid)
         self.stats["prefill_chunks"] += len(pre)
         return out
+
+    def _prefill_records(self, counts) -> None:
+        """A prefill chunk's per-layer bookkeeping from its expert counts
+        (np.int64, one row per layer): LoadTracker EMA, workload-trace
+        record, expert loads."""
+        with TraceAnnotation("engine.boundary"):
+            for layer in range(self.L):
+                if self._layer_kind(layer)[1] != "moe":
+                    continue
+                cnt = counts[layer // self.p, layer % self.p]
+                tracker = self.load_trackers.setdefault(
+                    layer, trajectory.LoadTracker(self.cfg.moe.num_experts,
+                                                  decay=self.scfg.ema_decay))
+                tracker.update(cnt)
+                self._record({
+                    "iter": self.iterations, "layer": layer,
+                    "phase": "prefill", "counts": cnt.copy(),
+                    "order": paired_load_order(cnt),
+                    "schedule": ("dynamic" if self.dynamic_schedule
+                                 else "static")})
+                self.stats["expert_loads"] += int((cnt > 0).sum())
 
     def step(self) -> List[Tuple[str, int]]:
         """One iteration, spanned as ``engine.step``.  JAX compile-path
@@ -796,25 +824,50 @@ class Engine:
     def _step_fused(self) -> List[Tuple[str, int]]:
         self.iterations += 1
         self.stats["iterations"] += 1
-        out = self._prefill_chunk_step(fused=True)
+        # sync-free when nothing at a boundary is decided on the host: no
+        # Algorithm-2 deferral, no EMA trajectory for the next segment.
+        # Read every step: the policy's threshold may change between them
+        sync_free = (self.policy.n_threshold >= _DEFER_OFF
+                     and not self.dynamic_schedule)
+        pending = [] if sync_free else None
+        out = self._prefill_chunk_step(fused=True, pending=pending)
         act = [r for r in self.active() if r.phase == "decode"]
         if not act:
-            return out
+            return self._settle_pass(pending, act, None, out) \
+                if sync_free else out
 
         ms = megastep.get_megastep(self.cfg, self.scfg)
         token_vec, start_mask = self._start_masks(act)
         bnds = ms.boundaries
+        if sync_free:
+            self.stats["sync_free_passes"] += 1
+            mask = self._pass_mask
+            start = mask(np.flatnonzero(start_mask))
+        else:
+            mask, start = self._mask, start_mask
 
         if not bnds:
             with TraceAnnotation("engine.dispatch", segment=ms.ONLY):
                 self._x, self.caches, logits = ms.seg_only(
                     self.params, self._x, self.caches,
                     jnp.asarray(self.cache_len), self._table_dev,
-                    token_vec, start_mask)
+                    token_vec, start)
             for r in act:
                 if start_mask[r.slot]:
                     r.progress = 2 * self.L
+            if sync_free:
+                return self._settle_pass(pending, act, logits, out)
             return self._finish(act, logits, out, fetch=True)
+
+        def boundary(layer, run_ffn, routing, counts):
+            if not sync_free:
+                return self._boundary_fused(layer, run_ffn, routing, counts,
+                                            ms)
+            # nothing to decide: the rows go on, the counts wait for the
+            # pass's one read
+            if run_ffn:
+                pending.append((layer, run_ffn, counts))
+            return run_ffn, ms.identity_order
 
         # segment 0: embed merge + layers [0, b0) + mixer(b0) + route(b0)
         b0 = bnds[0]
@@ -826,8 +879,8 @@ class Engine:
             cl = jnp.asarray(self.cache_len)
             self._x, self.caches, h, routing, counts = ms.seg_first(
                 self.params, self._x, self.caches, cl, self._table_dev,
-                token_vec, start_mask, self._mask([r.slot for r in run_ffn]))
-        kept, order = self._boundary_fused(b0, run_ffn, routing, counts, ms)
+                token_vec, start, mask([r.slot for r in run_ffn]))
+        kept, order = boundary(b0, run_ffn, routing, counts)
 
         for j, b in enumerate(bnds[1:], start=1):
             for r in kept:
@@ -838,18 +891,49 @@ class Engine:
                                  segment=ms.mid_names[j - 1]):
                 self._x, self.caches, h, routing, counts = ms.seg_mid[j - 1](
                     self.params, self._x, self.caches, cl, self._table_dev,
-                    h, routing, order, self._mask([r.slot for r in kept]),
-                    self._mask([r.slot for r in run_ffn]))
-            kept, order = self._boundary_fused(b, run_ffn, routing, counts,
-                                               ms)
+                    h, routing, order, mask([r.slot for r in kept]),
+                    mask([r.slot for r in run_ffn]))
+            kept, order = boundary(b, run_ffn, routing, counts)
 
         with TraceAnnotation("engine.dispatch", segment=ms.LAST):
             self._x, self.caches, logits = ms.seg_last(
                 self.params, self._x, self.caches, cl, self._table_dev,
-                h, routing, order, self._mask([r.slot for r in kept]))
+                h, routing, order, mask([r.slot for r in kept]))
         for r in kept:
             r.progress = 2 * self.L
+        if sync_free:
+            return self._settle_pass(pending, act, logits, out)
         return self._finish(act, logits, out, fetch=True)
+
+    def _pass_mask(self, slots):
+        """``_mask`` for the sync-free pass: every segment of a pass that
+        no row joins mid-way takes the same rows, so the last mask stays
+        on the device and is reused while the set of rows is unchanged
+        (at batch 1, always)."""
+        key = tuple(sorted(int(s) for s in slots))
+        if key != self._pass_slots:
+            self._pass_slots, self._pass_mask_dev = key, self._mask(list(key))
+        return self._pass_mask_dev
+
+    def _settle_pass(self, pending, act, logits, out):
+        """The sync-free pass's one read (site ``pass``): the prefill
+        chunk's and every boundary's expert counts, with the logits batch
+        if a row finished the pass.  Then the host bookkeeping in the
+        order of the per-boundary path: the prefill chunk's records,
+        each boundary's in order, the sampling."""
+        if not any(r.progress == 2 * self.L for r in act):
+            logits = None
+        if not pending and logits is None:
+            return out
+        counts, logits = self._fetch(([c for *_, c in pending], logits),
+                                     "pass")
+        for (layer, rows, _), cnt in zip(pending, counts):
+            cnt = np.asarray(cnt, np.int64)
+            if layer is None:
+                self._prefill_records(cnt)
+            else:
+                self._boundary_host(layer, rows, cnt, None, None)
+        return self._finish(act, logits, out)
 
     def _boundary_fused(self, layer, run_ffn, routing, counts_dev, ms):
         """Host work at one MoE boundary on the fused path: ONE device
@@ -877,7 +961,8 @@ class Engine:
     def _finish(self, act, logits, out, fetch=False):
         """Emit a token for every request that completed the pass, bump
         cache_len, reset progress.  ``fetch=True`` pulls the full logits
-        batch in one transfer (the fused path's single sampling sync)."""
+        batch in one transfer (the per-boundary fused path's sampling
+        read; the sync-free pass hands over logits it already read)."""
         cfg, scfg = self.cfg, self.scfg
         finish = [r for r in act if not r.done and r.progress == 2 * self.L]
         if not finish:

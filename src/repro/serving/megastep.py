@@ -6,7 +6,8 @@ paper's fine-grained overlap story, where the host must not be the
 bottleneck.  This module fuses everything *between* MoE boundaries into
 one compiled segment, so a steady-state decode iteration is ``k + 1``
 device dispatches (``k`` = number of MoE layers) with at most one host
-sync per boundary:
+sync per boundary, and one per iteration when no boundary needs a host
+decision:
 
 * ``seg_first``  — fresh-token embed merge, the full layers before the
   first boundary ``b0``, the mixer at ``b0``, and the *route* stage at
@@ -20,10 +21,14 @@ sync per boundary:
   full layers, final norm and logits;
 * ``seg_only``   — the no-MoE degenerate case (one segment end to end).
 
-Between segments the host does exactly the work that genuinely needs
-host values: the Algorithm-2 deferral decision, the workload-trace
-record, and the LoadTracker EMA update — one
-``jax.device_get((counts, indices))`` per boundary.  Every segment body
+Each segment returns its boundary's expert counts.  Where the host
+decides at a boundary (Algorithm-2 deferral, or the EMA trajectory of a
+dynamic schedule) it reads them between segments, one
+``jax.device_get((counts, indices))`` per boundary, and does the
+decision, the workload-trace record and the LoadTracker EMA update
+there.  Otherwise the engine dispatches every segment back to back and
+reads all the counts with the logits in one fetch (the sync-free pass,
+``repro.serving.engine``); the segments are the same.  Every segment body
 is built from the same ``transformer.decode_*`` entry points the legacy
 eager loop calls, so fused and legacy iterations are bit-identical by
 construction (asserted token-for-token and trace-for-trace in

@@ -22,18 +22,24 @@ PROMPTS = ((1, 2, 3, 4), (9, 8, 7))
 
 
 def _run(cfg, params, *, fused, chunked, schedule, slack=0.0, nthr=None,
-         kernels=False):
-    spec = {"strategy": "capacity"}
+         kernels=False, strategy="capacity", switch=None, **scfg):
+    """Serve PROMPTS to completion; ``switch`` = (iteration, threshold)
+    sets the deferral threshold after that many iterations."""
+    spec = {"strategy": strategy}
     if schedule:
         spec["schedule"] = schedule
     with use_kernels(kernels):
         eng = Engine(params, cfg, ServeConfig(
             max_batch=4, max_ctx=48, fused=fused, chunk_tokens=4,
-            buffering_slack=slack, theta_min=3, spec=spec))
+            buffering_slack=slack, theta_min=3, spec=spec, **scfg))
         if nthr:
             eng.policy.n_threshold = nthr
         sub = eng.submit_chunked if chunked else eng.submit
         rids = [sub(list(p), max_new=6) for p in PROMPTS]
+        if switch is not None:
+            for _ in range(switch[0]):
+                eng.step()
+            eng.policy.n_threshold = switch[1]
         outs = eng.run()
     return eng, [outs[r] for r in rids]
 
@@ -103,14 +109,60 @@ def test_fused_matches_legacy_with_deferral(setup):
     _assert_same(e0, o0, e1, o1)
 
 
-def test_steady_state_no_retrace_and_sync_budget(setup):
-    """The tentpole's acceptance criterion: steady-state decode triggers
-    ZERO retraces, and each iteration costs at most one host sync per
-    MoE boundary plus the single batched logits fetch."""
+def test_fused_matches_legacy_tiers_sync_free(setup):
+    """The sync-free pass writes its records after the pass; those that
+    read the EMA (``resident``, the hybrid ``hot`` partition, their
+    modeled seconds) still match the legacy loop record for record."""
+    cfg, params = setup
+    kw = dict(chunked=True, schedule=None, strategy="hybrid",
+              resident_budget_mb=1.0)
+    e0, o0 = _run(cfg, params, fused=False, **kw)
+    e1, o1 = _run(cfg, params, fused=True, **kw)
+    recs = [r for r in _workload(e1) if "counts" in r]
+    assert recs and all("resident" in r and "hot" in r for r in recs)
+    assert e1.stats["sync_free_passes"] > 0
+    _assert_same(e0, o0, e1, o1)
+
+
+@pytest.mark.parametrize("switch", [(2, 2), (3, 1 << 30)],
+                         ids=["deferral_on", "deferral_off"])
+def test_fused_matches_legacy_deferral_switched(setup, switch):
+    """The regime is read every step: lowering the threshold mid-run
+    leaves the sync-free path, raising it mid-run (rows deferred
+    mid-pass) enters it, and both match the legacy loop exactly."""
+    cfg, params = setup
+    slack = 0.0 if switch[1] < (1 << 29) else 0.5
+    nthr = None if slack == 0.0 else 2
+    e0, o0 = _run(cfg, params, fused=False, chunked=True, schedule=None,
+                  slack=slack, nthr=nthr, switch=switch)
+    e1, o1 = _run(cfg, params, fused=True, chunked=True, schedule=None,
+                  slack=slack, nthr=nthr, switch=switch)
+    assert e1.stats["deferrals"] > 0
+    # every iteration decodes: the passes are those on the sync-free side
+    assert e1.stats["sync_free_passes"] == (
+        switch[0] if slack == 0.0 else e1.stats["iterations"] - switch[0])
+    _assert_same(e0, o0, e1, o1)
+
+
+SYNC_CASES = {
+    # deferral off, static schedule: one read per decode iteration
+    "sync_free": dict(),
+    "dynamic": dict(spec={"strategy": "capacity", "schedule": "dynamic"}),
+    # deferral armed, no row deferred yet: counts and routing indices at
+    # every boundary, plus the logits
+    "deferral": dict(n_threshold=1000),
+}
+
+
+@pytest.mark.parametrize("case", list(SYNC_CASES))
+def test_steady_state_no_retrace_and_sync_budget(setup, case):
+    """Steady-state decode triggers ZERO retraces in every regime, and
+    the host reads once per iteration when no boundary needs a host
+    decision, else once per MoE boundary plus the logits batch."""
     cfg, params = setup
     megastep._CACHE.clear()
     eng = Engine(params, cfg, ServeConfig(max_batch=4, max_ctx=48,
-                                          chunk_tokens=4))
+                                          chunk_tokens=4, **SYNC_CASES[case]))
     for p in PROMPTS:
         eng.submit(list(p), max_new=12)
     eng.step()
@@ -118,13 +170,37 @@ def test_steady_state_no_retrace_and_sync_budget(setup):
     ms = megastep.get_megastep(eng.cfg, eng.scfg)
     assert ms.traces > 0
     t0, s0 = ms.traces, eng.stats["host_syncs"]
-    for _ in range(3):
+    p0 = eng.stats["sync_free_passes"]
+    n = 3
+    for _ in range(n):
         eng.step()
     nb = len(ms.boundaries)
     assert nb > 0
     assert ms.traces == t0, "steady-state decode retraced a segment"
-    assert eng.stats["host_syncs"] - s0 == 3 * (nb + 1), \
-        "more than one host sync per MoE boundary per iteration"
+    sync_free = case == "sync_free"
+    assert eng.stats["host_syncs"] - s0 == n * (1 if sync_free else nb + 1)
+    assert eng.stats["sync_free_passes"] - p0 == (n if sync_free else 0)
+
+
+def test_sync_free_leaves_when_threshold_lowered(setup):
+    """Lowering ``policy.n_threshold`` between steps arms deferral: the
+    next pass reads at every boundary and is not counted sync-free."""
+    cfg, params = setup
+    eng = Engine(params, cfg, ServeConfig(max_batch=4, max_ctx=48,
+                                          chunk_tokens=4))
+    for p in PROMPTS:
+        eng.submit(list(p), max_new=12)
+    eng.step()
+    s0, p0 = eng.stats["host_syncs"], eng.stats["sync_free_passes"]
+    eng.step()
+    assert eng.stats["host_syncs"] - s0 == 1
+    assert eng.stats["sync_free_passes"] - p0 == 1
+    eng.policy.n_threshold = 1000
+    s0, p0 = eng.stats["host_syncs"], eng.stats["sync_free_passes"]
+    eng.step()
+    nb = len(megastep.get_megastep(eng.cfg, eng.scfg).boundaries)
+    assert eng.stats["host_syncs"] - s0 == nb + 1
+    assert eng.stats["sync_free_passes"] == p0
 
 
 def test_fused_routes_each_moe_layer_once(setup, monkeypatch):
