@@ -1,4 +1,4 @@
-from .base import ModelConfig, MoEConfig, SSMConfig, FrontendConfig, get_config, list_configs, register
+from .base import ModelConfig, MoEConfig, MLAConfig, SSMConfig, FrontendConfig, get_config, list_configs, register
 from .shapes import SHAPES, SHAPE_ORDER, ShapeSpec, applicable, cells
 
 ASSIGNED_ARCHS = [

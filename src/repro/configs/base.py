@@ -44,6 +44,12 @@ class MoEConfig:
     impl: str = "dense"                # default strategy name when no
                                        # ExecutionSpec is given (a
                                        # repro.core.strategy registry key)
+    # router (repro.core.gating): "softmax" renormalises the top-k
+    # probabilities; "sigmoid" (DeepSeek-V3) chooses by sigmoid score
+    # plus a per-expert selection bias that does not enter the weights,
+    # renormalises the chosen scores and scales them by routed_scaling
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
 
     def __post_init__(self):
         assert self.top_k <= self.num_experts
@@ -51,6 +57,29 @@ class MoEConfig:
     def capacity_rows(self, tokens: int) -> int:
         return moe_capacity_rows(tokens, self.top_k, self.num_experts,
                                  self.capacity_factor)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values
+    are up-projected from one cached latent per token, and a rotary key
+    shared by every head is cached beside it."""
+
+    kv_lora_rank: int = 512            # width of the cached latent
+    qk_nope_head_dim: int = 128        # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 64         # RoPE'd width (shared key)
+    v_head_dim: int = 128
+    rope_interleave: bool = True       # RoPE on pairs (2i, 2i+1)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """Values cached per token per layer: the latent and the shared
+        rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -102,6 +131,11 @@ class ModelConfig:
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
     moe_every: int = 1                 # MoE FFN every k-th layer (others dense)
+    first_dense: int = 0               # leading layers with a dense FFN of
+                                       # width d_ff in place of the MoE
+                                       # (first_k_dense_replace)
+    mla: Optional[MLAConfig] = None    # latent attention in every
+                                       # attention layer
     ssm: Optional[SSMConfig] = None
     attn_every: int = 1                # hybrid: attention every k-th layer (others SSM)
     encoder_layers: int = 0            # enc-dec (whisper): encoder depth
@@ -142,7 +176,7 @@ class ModelConfig:
         return True
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Per-decoder-layer mixer kind: 'attn' or 'ssm'."""
+        """Per-decoder-layer mixer kind: 'attn', 'mla' or 'ssm'."""
         kinds = []
         for i in range(self.num_layers):
             if self.family == "ssm":
@@ -153,13 +187,15 @@ class ModelConfig:
                 # we use the last slot of each period for scan regularity).
                 kinds.append("attn" if (i % self.attn_every) == self.attn_every - 1 else "ssm")
             else:
-                kinds.append("attn")
+                kinds.append("attn" if self.mla is None else "mla")
         return tuple(kinds)
 
     def ffn_kinds(self) -> Tuple[str, ...]:
         kinds = []
         for i in range(self.num_layers):
-            if self.moe is not None and (i % self.moe_every) == self.moe_every - 1:
+            if i < self.first_dense:
+                kinds.append("dense")
+            elif self.moe is not None and (i % self.moe_every) == self.moe_every - 1:
                 kinds.append("moe")
             elif self.d_ff > 0:
                 kinds.append("dense")
@@ -174,6 +210,12 @@ class ModelConfig:
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         total = embed
         attn = d * (n_q * hd) + 2 * d * (n_kv * hd) + (n_q * hd) * d
+        if self.mla is not None:
+            m = self.mla
+            attn = (d * n_q * m.qk_head_dim + d * m.cache_width
+                    + m.kv_lora_rank
+                    + m.kv_lora_rank * n_q * (m.qk_nope_head_dim + m.v_head_dim)
+                    + n_q * m.v_head_dim * d)
         dense_ffn = (3 if self.activation == "swiglu" else 2) * d * self.d_ff
         moe_ffn = 0
         if self.moe is not None:
@@ -190,7 +232,7 @@ class ModelConfig:
                 + self.ssm.d_conv * (di + 2 * self.ssm.n_groups * self.ssm.d_state) \
                 + di * d + 2 * nh
         for i, (mix, ffn) in enumerate(zip(self.layer_kinds(), self.ffn_kinds())):
-            total += attn if mix == "attn" else ssm_p
+            total += ssm_p if mix == "ssm" else attn
             if ffn == "dense":
                 total += dense_ffn
             elif ffn == "moe":
@@ -247,6 +289,8 @@ _ARCH_MODULES = [
     "internvl2_2b", "mamba2_370m",
     # paper Table-I workload models (simulator + extra configs)
     "deepseek_moe_16b", "qwen3_30b_a3b", "yuan2_m32",
+    # DeepSeek-V3 block: latent attention, sigmoid router, dense layer 0
+    "kanana_2_30b_a3b",
 ]
 
 _loaded = False

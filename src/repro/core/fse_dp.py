@@ -146,6 +146,10 @@ def _ring_stream(xe, w_g, w_u, w_d, activation, axis, P_, micro_slices,
 
 def _route(wr, x2d, moe):
     """Pipeline *route* stage: Routing for the local token rows."""
+    if moe.scoring != "softmax":
+        raise NotImplementedError(
+            f"the sharded strategies route by softmax only, not "
+            f"{moe.scoring!r} (the selection bias is not passed in)")
     return gating.route({"w_router": wr}, x2d, top_k=moe.top_k)
 
 
@@ -289,7 +293,7 @@ def moe_fse_dp(params, x, moe: MoEConfig, activation, *, axis="model",
         shape = x.shape
         x2d = x.reshape(-1, shape[-1])
         if routing is None:
-            routing = gating.route(params["router"], x2d, top_k=moe.top_k)
+            routing = gating.route_moe(params["router"], x2d, moe)
         y = moe_capacity(params, x2d, routing, moe, activation,
                          schedule=schedule)
         return y.reshape(shape), gating.aux_load_balance_loss(routing, moe.num_experts)

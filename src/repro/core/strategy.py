@@ -537,7 +537,7 @@ class _SingleDevice:
         from repro.core import gating
         x2d = x.reshape(-1, x.shape[-1])
         if routing is None:
-            routing = gating.route(params["router"], x2d, top_k=moe.top_k)
+            routing = gating.route_moe(params["router"], x2d, moe)
         return x2d, routing
 
     # kept for any external callers of the old private helper

@@ -71,13 +71,23 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def apply_rope(x, positions, theta: float, interleave: bool = False):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+
+    Pair ``i`` (frequency ``theta**(-2i/hd)``) is the two halves' i-th
+    entries ``(i, i + hd/2)`` (rotate-half), or with ``interleave`` the
+    adjacent entries ``(2i, 2i+1)`` (DeepSeek-V3's ``rope_interleave``)."""
     hd = x.shape[-1]
     inv = jnp.asarray(rope_freqs(hd, theta))          # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * inv   # (..., seq, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(*x.shape[:-1], hd // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

@@ -27,7 +27,7 @@ def moe_init(key, d_model, moe: MoEConfig, activation, dtype):
     ks = jax.random.split(key, 5)
     E, de = moe.num_experts, moe.d_expert
     p = {
-        "router": gating.router_init(ks[0], d_model, E, dtype),
+        "router": gating.router_init(ks[0], d_model, E, dtype, moe.scoring),
         "w_up": _stack_init(ks[1], E, d_model, de, dtype),
         "w_down": _stack_init(ks[2], E, de, d_model, dtype),
     }

@@ -7,11 +7,16 @@ slot-wise stacked parameters.  This keeps the lowered HLO size
 O(period) instead of O(num_layers) — essential for the 96-layer
 nemotron-4-340b dry-run — while supporting heterogeneous layer plans.
 
-Caches (KV / SSM state) are carried through the same scan as per-period
-xs/ys so decode works for every family.
+Caches (KV / latent / SSM state) are carried through the same scan as
+per-period xs/ys so decode works for every family.
+
+Leading dense layers (``ModelConfig.first_dense``) break the periodicity,
+so such a stack's period is the whole depth: every layer is a slot of
+its own, stacked over one period.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -20,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from . import attention as attn_mod
 from . import mamba2 as ssm_mod
+from . import mla as mla_mod
 from . import moe as moe_mod
 from .layers import embed_init, norm_init, apply_norm
 from .mlp import ffn_init, ffn
@@ -50,6 +56,9 @@ def _slot_init(key, cfg: ModelConfig, mixer: str, ffn_kind: str):
         slot["attn"] = attn_mod.attn_init(
             ks[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
             cfg.resolved_head_dim, jnp.dtype(cfg.dtype))
+    elif mixer == "mla":
+        slot["mla"] = mla_mod.mla_init(ks[0], cfg.d_model, cfg.num_heads,
+                                       cfg.mla, jnp.dtype(cfg.dtype))
     else:
         slot["ssm"] = ssm_mod.mamba2_init(ks[0], cfg.d_model, cfg.ssm, jnp.dtype(cfg.dtype))
     if ffn_kind != "none":
@@ -102,12 +111,19 @@ def _needs_unroll(spec) -> bool:
     return spec is not None and bool(spec.layer_overrides)
 
 
+def _mla_kw(cfg: ModelConfig) -> dict:
+    return dict(n_heads=cfg.num_heads, mla=cfg.mla, rope_theta=cfg.rope_theta)
+
+
 def _apply_slot_full(slot, x, cfg: ModelConfig, mixer, ffn_kind, *,
                      positions=None, spec=None, phase="train", layer=None,
                      use_flash=False):
     """Full-sequence forward for one layer slot. Returns (x, aux)."""
     h = apply_norm(cfg.norm, slot["norm1"], x)
-    if mixer == "attn":
+    if mixer == "mla":
+        h = mla_mod.mla_attention(slot["mla"], h, positions=positions,
+                                  **_mla_kw(cfg))
+    elif mixer == "attn":
         h = attn_mod.attention(slot["attn"], h, n_heads=cfg.num_heads,
                                n_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
                                rope_theta=cfg.rope_theta, positions=positions,
@@ -129,7 +145,8 @@ def _apply_slot_full(slot, x, cfg: ModelConfig, mixer, ffn_kind, *,
 
 
 class SlotCache(NamedTuple):
-    """Per-slot decode cache — exactly one of kv / ssm is meaningful."""
+    """Per-slot decode cache — exactly one of kv / ssm is meaningful
+    (``kv`` holds a KVCache, or a LatentCache for ``mla`` slots)."""
     kv: Any
     ssm: Any
 
@@ -137,7 +154,11 @@ class SlotCache(NamedTuple):
 def _apply_slot_decode(slot, x, cache: SlotCache, cache_len, cfg: ModelConfig,
                        mixer, ffn_kind, *, spec=None, layer=None):
     h = apply_norm(cfg.norm, slot["norm1"], x)
-    if mixer == "attn":
+    if mixer == "mla":
+        h, new_kv = mla_mod.mla_append(slot["mla"], h, cache.kv, cache_len,
+                                       **_mla_kw(cfg))
+        new_cache = SlotCache(new_kv, cache.ssm)
+    elif mixer == "attn":
         h, new_kv = attn_mod.attention_decode(
             slot["attn"], h, cache.kv, cache_len, n_heads=cfg.num_heads,
             n_kv=cfg.num_kv_heads, head_dim=cfg.resolved_head_dim,
@@ -248,9 +269,11 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int):
     dtype = jnp.dtype(cfg.dtype)
     caches = []
     for mixer, _ in plan:
-        if mixer == "attn":
-            kv = attn_mod.init_kv_cache(batch, max_seq, cfg.num_kv_heads,
-                                        cfg.resolved_head_dim, dtype)
+        if mixer in ("attn", "mla"):
+            kv = (mla_mod.init_latent_cache(batch, max_seq, cfg.mla, dtype)
+                  if mixer == "mla" else
+                  attn_mod.init_kv_cache(batch, max_seq, cfg.num_kv_heads,
+                                         cfg.resolved_head_dim, dtype))
             kv = jax.tree.map(lambda a: jnp.broadcast_to(a, (n_periods,) + a.shape), kv)
             caches.append(SlotCache(kv, ()))
         else:
@@ -265,7 +288,10 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
     """Stacked per-period caches with attention KV in pool pages.
 
     Attention slots hold (n_periods, num_pages, page_size, n_kv, hd)
-    physical pages shared by every serving slot through one page table
+    physical pages (``mla`` slots: latent pages, (n_periods, num_pages,
+    page_size, kv_lora_rank) and (..., qk_rope_head_dim), the
+    ``cache_width`` values per token) shared by every serving slot
+    through one page table
     (``repro.serving.statepool``); SSM slots keep dense per-row state —
     it is O(1) per slot, so the pool snapshots it by value instead of
     paging it.  Page allocation is in lockstep across layers, so a
@@ -275,10 +301,13 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
     dtype = jnp.dtype(cfg.dtype)
     caches = []
     for mixer, _ in plan:
-        if mixer == "attn":
-            kv = attn_mod.init_paged_kv_cache(num_pages, page_size,
-                                              cfg.num_kv_heads,
-                                              cfg.resolved_head_dim, dtype)
+        if mixer in ("attn", "mla"):
+            kv = (mla_mod.init_latent_cache(num_pages, page_size, cfg.mla,
+                                            dtype)
+                  if mixer == "mla" else
+                  attn_mod.init_paged_kv_cache(num_pages, page_size,
+                                               cfg.num_kv_heads,
+                                               cfg.resolved_head_dim, dtype))
             kv = jax.tree.map(lambda a: jnp.broadcast_to(a, (n_periods,) + a.shape), kv)
             caches.append(SlotCache(kv, ()))
         else:
@@ -307,7 +336,13 @@ def prefill(params, tokens, cfg: ModelConfig, max_seq: int, *,
         new_caches = []
         for s, (mixer, ffn_kind) in enumerate(plan):
             h = apply_norm(cfg.norm, period_params[s]["norm1"], x)
-            if mixer == "attn":
+            if mixer == "mla":
+                h, kv = mla_mod.mla_append(
+                    period_params[s]["mla"], h,
+                    mla_mod.init_latent_cache(B, max_seq, cfg.mla, x.dtype),
+                    jnp.zeros((B,), jnp.int32), **_mla_kw(cfg))
+                new_caches.append(SlotCache(kv, ()))
+            elif mixer == "attn":
                 kv = attn_mod.prefill_kv(period_params[s]["attn"], h,
                                          n_kv=cfg.num_kv_heads,
                                          head_dim=cfg.resolved_head_dim,
@@ -396,7 +431,16 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
         for s, (mixer, ffn_kind) in enumerate(plan):
             with jax.named_scope(mixer):
                 h = apply_norm(cfg.norm, period_params[s]["norm1"], x)
-                if mixer == "attn":
+                if mixer == "mla":
+                    append = (mla_mod.mla_append if page_table is None else
+                              partial(mla_mod.mla_append_paged,
+                                      page_table=page_table))
+                    h, kv = append(period_params[s]["mla"], h,
+                                   period_caches[s].kv, cache_len=cache_len,
+                                   token_mask=token_mask, scope="mla_prefill",
+                                   **_mla_kw(cfg))
+                    new_caches.append(SlotCache(kv, period_caches[s].ssm))
+                elif mixer == "attn":
                     if page_table is not None:
                         h, kv = attn_mod.attention_append_paged(
                             period_params[s]["attn"], h, period_caches[s].kv,
@@ -428,10 +472,9 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
                         # route ONCE: the same Routing feeds the trace
                         # counts and the expert execution
                         with jax.named_scope("route"):
-                            routing = gating.route(
+                            routing = gating.route_moe(
                                 period_params[s]["moe"]["router"],
-                                h.reshape(-1, h.shape[-1]),
-                                top_k=cfg.moe.top_k)
+                                h.reshape(-1, h.shape[-1]), cfg.moe)
                             cnt = gating.expert_token_counts(
                                 routing, token_mask.reshape(-1)
                             ).astype(jnp.int32)
@@ -440,7 +483,8 @@ def prefill_chunk(params, tokens, caches, cache_len, cfg: ModelConfig, *,
                                           phase="prefill", layer=layer,
                                           routing=routing)
                 else:
-                    h = ffn(period_params[s]["ffn"], h, cfg.activation)
+                    with jax.named_scope("dense_ffn"):
+                        h = ffn(period_params[s]["ffn"], h, cfg.activation)
                 x = x + h
             counts.append(cnt)
         return x, (tuple(new_caches), jnp.stack(counts))
@@ -524,20 +568,31 @@ def decode_mixer(params, x, caches, cache_len, cfg: ModelConfig,
     with jax.named_scope(mixer):
         mask = jnp.asarray(mask)
         h = apply_norm(cfg.norm, slot["norm1"], x)
-        if mixer == "attn" and page_table is not None:
+        if mixer in ("attn", "mla") and page_table is not None:
             pages = jax.tree.map(lambda a: a[period_idx], caches[slot_i].kv)
-            h, new_pages = attn_mod.attention_decode_paged(
-                slot["attn"], h, pages, page_table, cache_len,
-                n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-                row_mask=mask)
+            if mixer == "mla":
+                h, new_pages = mla_mod.mla_append_paged(
+                    slot["mla"], h, pages, page_table, cache_len,
+                    token_mask=mask[:, None], scope="mla_decode",
+                    **_mla_kw(cfg))
+            else:
+                h, new_pages = attn_mod.attention_decode_paged(
+                    slot["attn"], h, pages, page_table, cache_len,
+                    n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
+                    head_dim=cfg.resolved_head_dim,
+                    rope_theta=cfg.rope_theta, row_mask=mask)
             new_stack = jax.tree.map(lambda st, n: st.at[period_idx].set(n),
                                      caches[slot_i].kv, new_pages)
             caches = tuple(c if i != slot_i else SlotCache(new_stack, c.ssm)
                            for i, c in enumerate(caches))
             return jnp.where(mask[:, None, None], x + h, x), caches
         cache = jax.tree.map(lambda a: a[period_idx], caches[slot_i])
-        if mixer == "attn":
+        if mixer == "mla":
+            h, new_kv = mla_mod.mla_append(
+                slot["mla"], h, cache.kv, cache_len,
+                token_mask=mask[:, None], scope="mla_decode", **_mla_kw(cfg))
+            new_cache = SlotCache(new_kv, cache.ssm)
+        elif mixer == "attn":
             h, new_kv = attn_mod.attention_decode(
                 slot["attn"], h, cache.kv, cache_len,
                 n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
@@ -576,8 +631,8 @@ def decode_route(params, x, cfg: ModelConfig, layer: int, count_mask=None):
     slot = _layer_slot(params, layer, p)
     with jax.named_scope("route"):
         h = apply_norm(cfg.norm, slot["norm2"], x)
-        routing = gating.route(slot["moe"]["router"], h[:, 0, :],
-                               top_k=cfg.moe.top_k)
+        routing = gating.route_moe(slot["moe"]["router"], h[:, 0, :],
+                                   cfg.moe)
         counts = None
         if count_mask is not None:
             counts = gating.expert_token_counts(routing,
@@ -607,8 +662,9 @@ def decode_ffn(params, x, cfg: ModelConfig, layer: int, mask):
         return x
     slot = _layer_slot(params, layer, p)
     mask = jnp.asarray(mask)
-    h = apply_norm(cfg.norm, slot["norm2"], x)
-    h = ffn(slot["ffn"], h, cfg.activation)
+    with jax.named_scope("dense_ffn"):
+        h = apply_norm(cfg.norm, slot["norm2"], x)
+        h = ffn(slot["ffn"], h, cfg.activation)
     return jnp.where(mask[:, None, None], x + h, x)
 
 

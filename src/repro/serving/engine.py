@@ -346,6 +346,9 @@ class Engine:
             max_prefix_entries=scfg.max_prefix_entries,
             bytes_per_page=page_b, ssm_bytes_per_row=ssm_b)
         self._has_ssm = statepool.has_ssm(self.caches)
+        # a latent-attention model's pages hold only latents
+        self._latent_page_b = (page_b if statepool.has_latent(self.caches)
+                               else 0)
         self._table_dev = jnp.asarray(self.pool.table)
         # host-side cache lengths: mutated in place (no device round-trip
         # per finished token), converted to a device array at call sites
@@ -377,6 +380,9 @@ class Engine:
                       # JAX trace / lowering / compile / cache-load events
                       # inside Engine.step, and their seconds
                       "compiles": 0, "compile_s": 0.0,
+                      # bytes of latent-attention pages in use, sampled
+                      # at each step once its pages are allocated
+                      "latent_cache_bytes": 0,
                       "preemptions": 0, "restores": 0}
         # state-pool counters (pages in use / peak, cache hit/miss/evict,
         # prefill tokens saved, resident bytes) live in the same dict:
@@ -792,6 +798,8 @@ class Engine:
             # allocate pages for this iteration's KV writes and push the
             # table once; it enters every jitted segment as a traced array
             self._ensure_pages()
+            self.stats["latent_cache_bytes"] = (self.pool.pages_in_use()
+                                                * self._latent_page_b)
             from repro.parallel import meshctx
             if self.scfg.fused and meshctx.get_mesh() is None:
                 out = self._step_fused()

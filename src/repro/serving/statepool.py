@@ -5,7 +5,8 @@ pinned per slot for the request's whole lifetime.  This module replaces
 that with a **block/paged pool** (the vLLM PagedAttention idea, adapted
 to the hybrid attn/SSM stacks this repo serves):
 
-* **Attention KV** lives in fixed-size physical pages
+* **Attention KV** (for latent attention: the latent and the shared
+  rotary key, ``models.mla.LatentCache``) lives in fixed-size physical pages
   (``page_size`` tokens each, ``num_pages`` total per engine) shared by
   every serving slot.  One host-side page table (``(max_batch, NP)``
   int32, ``NP = ceil(max_ctx / page_size)``) maps logical to physical
@@ -60,6 +61,11 @@ import numpy as np
 
 from repro.models import mamba2 as ssm_mod
 from repro.models.attention import KVCache
+from repro.models.mla import LatentCache
+
+# per-token state held in pool pages: attention K/V, or the latent and
+# shared rotary key of latent attention
+PAGED = (KVCache, LatentCache)
 
 
 class PoolExhausted(RuntimeError):
@@ -357,7 +363,7 @@ def copy_page(caches, src: int, dst: int):
     copy-on-write step for partial tail pages)."""
     out = []
     for c in caches:
-        if isinstance(c.kv, KVCache):
+        if isinstance(c.kv, PAGED):
             kv = jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), c.kv)
             out.append(type(c)(kv, c.ssm))
         else:
@@ -400,6 +406,10 @@ def has_ssm(caches) -> bool:
     return any(_is_ssm(c.ssm) for c in caches)
 
 
+def has_latent(caches) -> bool:
+    return any(isinstance(c.kv, LatentCache) for c in caches)
+
+
 def merge_prefill(caches, dense_caches, page_ids: List[int], slot: int,
                   page_size: int):
     """Scatter a one-shot (batch=1) dense prefill into the pool.
@@ -411,7 +421,7 @@ def merge_prefill(caches, dense_caches, page_ids: List[int], slot: int,
     n = len(page_ids)
     out = []
     for c, d in zip(caches, dense_caches):
-        if isinstance(c.kv, KVCache):
+        if isinstance(c.kv, PAGED):
             def put(pages, dense):
                 arr = dense[:, 0]                      # (n_periods, S, ...)
                 need = n * page_size
@@ -436,12 +446,12 @@ def merge_prefill(caches, dense_caches, page_ids: List[int], slot: int,
 
 
 def state_bytes(caches) -> Tuple[int, int]:
-    """(bytes per physical page across all attn layers, SSM bytes per
+    """(bytes per physical page across all attn / mla layers, SSM bytes per
     slot row across all SSM layers) — the pool's accounting constants."""
     page_b = 0
     ssm_b = 0
     for c in caches:
-        if isinstance(c.kv, KVCache):
+        if isinstance(c.kv, PAGED):
             for a in c.kv:
                 # (n_periods, P, page_size, n_kv, hd): per page = all but P
                 page_b += int(a.shape[0] * np.prod(a.shape[2:])) * a.dtype.itemsize
