@@ -23,12 +23,14 @@ EMA-tracked counts via :class:`LoadTracker`) or in-graph from the
 current call's own routing (:func:`traced_order`).
 
 The SPMD realization of a trajectory is a permutation of the expert
-axis of the dispatched ``(E, C, d)`` buffer and the matching weight
-stacks.  That axis is a pure batch axis of the grouped expert GEMM (the
-Pallas kernel grids over it in order, so the permutation genuinely
-reorders per-expert compute/weight-load timing), and the outputs are
-un-permuted *before* the combine — so a dynamic schedule changes
-execution order only, never values.  This is the paper's virtualization
+groups the grouped expert GEMM runs through in order: the ragged
+capacity path lays its groups out in trajectory order (the kernel's
+tile map then streams each expert's weights in that order), the padded
+``(E, C, d)`` layouts permute the expert axis of the dispatched buffer
+and the matching weight stacks.  Either way the permutation genuinely
+reorders per-expert compute/weight-load timing, and the combine sums
+each token's expert outputs in an order it does not touch — so a
+dynamic schedule changes execution order only, never values.  This is the paper's virtualization
 argument (§III) made checkable: ``tests`` assert dynamic == static bit
 for bit while the chiplet simulator (``sim.modes.simulate_trajectory``)
 shows the paired trajectory beating the static one in step time on

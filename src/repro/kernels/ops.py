@@ -8,13 +8,15 @@ chip run can never measure the oracle in place of the kernels.
 :func:`interpret_default` is the one rule for how a kernel lowers: in
 the Pallas interpreter on the CPU backend, natively everywhere else.
 
-``streamed_moe`` is the dispatch layer the model code calls: both the
-FSE-DP ring step (``repro.core.fse_dp._expert_partial``) and the
-single-device capacity path (``repro.models.moe.moe_capacity``) flow
-through it, so the paper's micro-slice kernel is the hot path whenever
-kernels are enabled.  The Pallas branch carries a custom VJP (backward
-derived from the jnp oracle) so gradients flow through training and the
-FSE-DP ring transpose without a hand-written backward kernel.
+``streamed_moe`` (the padded ``(E, C, d)`` layout: the FSE-DP ring step
+``repro.core.fse_dp._expert_partial``, the hybrid tiers, the EP/TP
+baselines) and ``ragged_moe`` (rows grouped by expert: the single-device
+capacity path ``repro.models.moe.moe_capacity``) are the dispatch layer
+the model code calls, so the paper's micro-slice kernel is the hot path
+whenever kernels are enabled.  Each Pallas branch carries a custom VJP
+(backward derived from the jnp oracle) so gradients flow through
+training and the FSE-DP ring transpose without a hand-written backward
+kernel.
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ import os
 import jax
 
 from . import quant, ref
-from .streamed_moe import streamed_moe_kernel
+from .streamed_moe import ragged_moe_kernel, streamed_moe_kernel
 from .flash_attention import flash_attention_kernel
 from .ssd import ssd_intra_chunk_kernel
 
@@ -70,22 +72,33 @@ def kernels_enabled() -> bool:
 # streamed_moe — differentiable kernel dispatch
 # ---------------------------------------------------------------------------
 
-def _streamed_moe_raw(activation, weight_dtype, opts, xe, w_g, w_u, w_d):
+def _streamed_weights(weight_dtype, w_g, w_u, w_d):
+    """The expert weights as the kernel streams them, and their scale
+    kwargs.  int8/fp8 quantize in-graph at the dispatch layer: params
+    keep their original dtype; the kernel streams int8/fp8 blocks plus
+    per-(expert, output-channel) scale rows and dequantizes in VMEM."""
     if weight_dtype in quant.QUANTIZED:
-        # quantize in-graph at the dispatch layer: params keep their
-        # original dtype; the kernel streams int8/fp8 blocks plus
-        # per-(expert, output-channel) scale rows and dequantizes in VMEM
         s_g = None
         if w_g is not None:
             w_g, s_g = quant.quantize(w_g, weight_dtype)
         w_u, s_u = quant.quantize(w_u, weight_dtype)
         w_d, s_d = quant.quantize(w_d, weight_dtype)
-        return streamed_moe_kernel(xe, w_g, w_u, w_d, activation=activation,
-                                   s_g=s_g, s_u=s_u, s_d=s_d, **dict(opts))
-    return streamed_moe_kernel(xe, quant.storage_cast(w_g, weight_dtype),
-                               quant.storage_cast(w_u, weight_dtype),
-                               quant.storage_cast(w_d, weight_dtype),
-                               activation=activation, **dict(opts))
+        return (w_g, w_u, w_d), dict(s_g=s_g, s_u=s_u, s_d=s_d)
+    return tuple(quant.storage_cast(w, weight_dtype)
+                 for w in (w_g, w_u, w_d)), {}
+
+
+def _weight_dtype(kw):
+    """The streamed weight format: the ``weight_dtype`` kwarg (popped
+    from ``kw``), else the ambient ``quant.use_weight_dtype``."""
+    wdt = quant.check_weight_dtype(kw.pop("weight_dtype", None))
+    return quant.weight_dtype() if wdt is None else wdt
+
+
+def _streamed_moe_raw(activation, weight_dtype, opts, xe, w_g, w_u, w_d):
+    ws, scales = _streamed_weights(weight_dtype, w_g, w_u, w_d)
+    return streamed_moe_kernel(xe, *ws, activation=activation, **scales,
+                               **dict(opts))
 
 
 _streamed_moe_diff = jax.custom_vjp(_streamed_moe_raw,
@@ -121,15 +134,66 @@ def streamed_moe(xe, w_g, w_u, w_d, activation: str, **kw):
     the identical quantize→dequantize round-trip, so ``use_kernels(False)``
     stays the ground truth at any weight dtype."""
     kw = dict(kw)
-    wdt = quant.check_weight_dtype(kw.pop("weight_dtype", None))
-    if wdt is None:
-        wdt = quant.weight_dtype()
+    wdt = _weight_dtype(kw)
     if not kernels_enabled():
         if wdt is None:
             return ref.streamed_moe_ref(xe, w_g, w_u, w_d, activation)
         return ref.streamed_moe_quant_ref(xe, w_g, w_u, w_d, activation, wdt)
     opts = tuple(sorted(kw.items()))
     return _streamed_moe_diff(activation, wdt, opts, xe, w_g, w_u, w_d)
+
+
+# ---------------------------------------------------------------------------
+# ragged_moe — the same, over rows grouped by expert (moe_capacity's layout)
+# ---------------------------------------------------------------------------
+
+def _ragged_moe_raw(activation, weight_dtype, opts, xs, sizes, order, w_g,
+                    w_u, w_d):
+    ws, scales = _streamed_weights(weight_dtype, w_g, w_u, w_d)
+    return ragged_moe_kernel(xs, sizes, order, *ws, activation=activation,
+                             **scales, **dict(opts))
+
+
+_ragged_moe_diff = jax.custom_vjp(_ragged_moe_raw, nondiff_argnums=(0, 1, 2))
+
+
+def _ragged_moe_fwd(activation, weight_dtype, opts, xs, sizes, order, w_g,
+                    w_u, w_d):
+    out = _ragged_moe_raw(activation, weight_dtype, opts, xs, sizes, order,
+                          w_g, w_u, w_d)
+    return out, (xs, sizes, order, w_g, w_u, w_d)
+
+
+def _ragged_moe_bwd(activation, weight_dtype, opts, res, g):
+    # straight-through, as streamed_moe: the full-precision oracle's VJP
+    xs, sizes, order, w_g, w_u, w_d = res
+    _, vjp = jax.vjp(
+        lambda xs, wg, wu, wd: ref.ragged_moe_ref(xs, sizes, order, wg, wu,
+                                                  wd, activation),
+        xs, w_g, w_u, w_d)
+    d_xs, d_wg, d_wu, d_wd = vjp(g)
+    return d_xs, None, None, d_wg, d_wu, d_wd
+
+
+_ragged_moe_diff.defvjp(_ragged_moe_fwd, _ragged_moe_bwd)
+
+
+def ragged_moe(xs, sizes, order, w_g, w_u, w_d, activation: str, **kw):
+    """Grouped expert FFN over rows grouped by expert
+    (``streamed_moe.ragged_moe_kernel``: xs (N,Tc,d), ``sizes`` rows per
+    group in layout order, ``order`` the expert of each group or None).
+    ``weight_dtype`` and the oracle fallback under ``use_kernels(False)``
+    as :func:`streamed_moe`; the oracle is ``ref.ragged_moe_ref``."""
+    kw = dict(kw)
+    wdt = _weight_dtype(kw)
+    if not kernels_enabled():
+        if wdt is None:
+            return ref.ragged_moe_ref(xs, sizes, order, w_g, w_u, w_d,
+                                      activation)
+        return ref.ragged_moe_quant_ref(xs, sizes, order, w_g, w_u, w_d,
+                                        activation, wdt)
+    return _ragged_moe_diff(activation, wdt, tuple(sorted(kw.items())), xs,
+                            sizes, order, w_g, w_u, w_d)
 
 
 def streamed_moe_autotuned(xe, w_g, w_u, w_d, activation: str):
@@ -139,9 +203,9 @@ def streamed_moe_autotuned(xe, w_g, w_u, w_d, activation: str):
     lowering), ``analytic`` (cost-model tiles), or ``measured``
     (wall-clock-timed tiles memoized under ``artifacts/autotune/``).
 
-    This is the one scheduler every expert-FFN path dispatches through:
-    the FSE-DP ring step, the EP/TP baselines, and the single-device
-    capacity path.  The ambient weight dtype feeds the planner its
+    This is the one scheduler every padded-layout expert-FFN path
+    dispatches through: the FSE-DP ring step, the hybrid tiers and the
+    EP/TP baselines.  The ambient weight dtype feeds the planner its
     streamed bytes-per-param, so quantized runs plan (and cost) larger
     hidden tiles per VMEM block."""
     opts = {}

@@ -43,6 +43,43 @@ def streamed_moe_quant_ref(xe, w_g, w_u, w_d, activation: str,
                             activation)
 
 
+def ragged_moe_ref(xs, sizes, order, w_g, w_u, w_d, activation: str):
+    """Oracle of ``streamed_moe.ragged_moe_kernel``: xs (N,Tc,d) rows in
+    groups of ``sizes`` rows (layout order), group g run by expert
+    ``order[g]`` (None = g) -> (N,Tc,d) fp32, zeros past the groups.
+    ``jax.lax.ragged_dot`` per projection, so the work is the routed
+    rows' wherever XLA lowers it natively."""
+    N, Tc, d = xs.shape
+    if order is not None:
+        w_g, w_u, w_d = (None if w is None else jnp.take(w, order, axis=0)
+                         for w in (w_g, w_u, w_d))
+    x = xs.reshape(N * Tc, d)
+    sizes = sizes.astype(jnp.int32)
+    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+    if activation == "swiglu":
+        if w_g is None:
+            raise ValueError("activation='swiglu' requires w_g")
+        h = jax.nn.silu(dot(x, w_g)) * dot(x, w_u)
+    elif activation == "relu2":
+        h = jnp.square(jax.nn.relu(dot(x, w_u)))
+    elif activation == "gelu":
+        h = jax.nn.gelu(dot(x, w_u))
+    else:
+        raise ValueError(activation)
+    return dot(h, w_d).astype(jnp.float32).reshape(N, Tc, d)
+
+
+def ragged_moe_quant_ref(xs, sizes, order, w_g, w_u, w_d, activation: str,
+                         weight_dtype: str):
+    """:func:`ragged_moe_ref` on weights round-tripped through the
+    streamed storage format, in fp32 (as :func:`streamed_moe_quant_ref`)."""
+    from . import quant
+    return ragged_moe_ref(xs.astype(jnp.float32), sizes, order,
+                          quant.fake_quant(w_g, weight_dtype),
+                          quant.fake_quant(w_u, weight_dtype),
+                          quant.fake_quant(w_d, weight_dtype), activation)
+
+
 # ---------------------------------------------------------------------------
 # flash attention (causal)
 # ---------------------------------------------------------------------------

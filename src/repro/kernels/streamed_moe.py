@@ -1,26 +1,42 @@
-"""Pallas TPU kernel: grouped expert GEMM over one d_expert micro-slice.
+"""Pallas TPU kernel: ragged grouped expert GEMM over one d_expert
+micro-slice.
 
-This is the compute hot-spot of FSE-DP's ring step (paper §IV): the
-kernel body is the per-chiplet "SRAM" level of the adaptation — it
+Rows arrive grouped by expert in tiles of ``Tc`` rows, each tile
+holding rows of one expert only: ``xs`` is ``(N, Tc, d)``.  A
+scalar-prefetched tile -> expert map, and the count of tiles in use,
+tell every grid step whose weight blocks to stream.  Consecutive tiles
+of one expert keep the same weight block index, so Pallas fetches that
+expert's weights once; an expert with no rows has no tile, so its
+weights are never read from HBM.  Tiles past the used count point at
+the last used expert's last blocks (no new DMA), skip their compute
+with ``pl.when`` and write zeros.  The single-device capacity path
+(``repro.models.moe.moe_capacity``) sorts its (token, expert) pairs
+into this layout, so one call streams only the experts it hits and
+computes about the routed rows — the paper's "stream only what the
+trajectory needs".
+
+The kernel body is the per-chiplet "SRAM" level of the adaptation: it
 holds exactly **one weight micro-slice** (w_g/w_u: (d, m), w_d: (m, d))
-plus one token tile in VMEM while computing the partial expert output,
-mirroring the paper's claim that on-chip residency is one micro-slice
-per stream.  HBM→VMEM pipelining across grid steps is Pallas's
-automatic double-buffering of the BlockSpec'd operands (the DDR→SRAM
-flow of Fig. 6); the D2D hop between chips is the ``ppermute`` in
+plus one token tile in VMEM while computing the partial expert output.
+HBM->VMEM pipelining across grid steps is Pallas's automatic
+double-buffering of the BlockSpec'd operands (the DDR->SRAM flow of
+Fig. 6); the D2D hop between chips is the ``ppermute`` in
 ``repro.core.fse_dp`` one level up.
 
-Grid: (E, C/Tc, d/Tj, m/Tk, d/Ti) — experts outer so weight blocks are
-revisited across token tiles of the same expert; the three inner dims
-tile the output d_model (j), the micro-slice hidden dim (k) and the
+Grid: (N, d/Tj, m/Tk, d/Ti) — tiles outer; the three inner dims tile
+the output d_model (j), the micro-slice hidden dim (k) and the
 contraction d_model (i) so micro-slices larger than one VMEM block
 still lower.  The pre-activation accumulates in a VMEM scratch over
 ``i``; the second GEMM accumulates into the (revisited) output block
 over ``k`` — both reduction dims are grid-minor, which is the Pallas
-requirement for accumulate-safe block revisiting.  With the default
-tile sizes (full d/m) the grid degenerates to the classic (E, C/Tc)
-form.  Gateless activations (relu2 / gelu) lower without a w_gate
-operand at all, so no placeholder slice is ever shipped HBM→VMEM.
+requirement for accumulate-safe block revisiting.  Gateless activations
+(relu2 / gelu) lower without a w_gate operand at all, so no placeholder
+slice is ever shipped HBM->VMEM.  Zero rows (a group's padding) come
+out as zero rows under all three activations.
+
+:func:`streamed_moe_kernel` keeps the padded ``(E, C, d)`` layout that
+FSE-DP's ring and the hybrid tiers dispatch: the same kernel with every
+expert given ``C`` rows.
 """
 from __future__ import annotations
 
@@ -123,7 +139,44 @@ def resolve_tiles(C: int, d: int, m: int, *, gated: bool, x_bytes: int = 2,
     return Tc, Tj, Tk
 
 
-def _kernel(*refs, activation, quantized, nI, C, Tc):
+def ragged_token_tile(pairs: int, num_experts: int) -> int:
+    """Token-tile rows of the ragged layout for ``pairs`` routed (token,
+    expert) pairs over ``num_experts``: twice the mean rows of an expert
+    that is hit, as a power of two from SUBLANE up to
+    DEFAULT_TOKEN_TILE.  Most experts then take one tile (one pass over
+    their weights), and a decode call's one or two rows per expert are
+    not padded out to a prefill-sized tile."""
+    want = -(-2 * pairs // max(1, min(num_experts, pairs)))
+    Tc = SUBLANE
+    while Tc < min(want, DEFAULT_TOKEN_TILE):
+        Tc *= 2
+    return Tc
+
+
+def ragged_tiles(pairs: int, num_experts: int, Tc: int) -> int:
+    """Static tile count N that holds ``pairs`` rows grouped by expert,
+    each group padded to a multiple of ``Tc``: at most
+    ``min(E, pairs)`` groups, each wasting at most ``Tc - 1`` rows."""
+    return (pairs + min(num_experts, pairs) * (Tc - 1)) // Tc
+
+
+def tile_map(sizes, order, Tc: int, N: int):
+    """(tile -> expert (N,) int32, tiles in use (1,) int32) for groups of
+    ``sizes`` rows (multiples of ``Tc``), laid out in ``order`` (group g
+    is expert ``order[g]``; ``None`` = canonical).  Tiles past the used
+    count map to the last used group's expert."""
+    ends = jnp.cumsum(sizes.astype(jnp.int32)) // Tc      # (G,) tile ends
+    used = ends[-1]
+    # compare_all: one (N, G) comparison, no loop on the device
+    find = lambda v: jnp.searchsorted(ends, v, side="right",
+                                      method="compare_all")
+    g = jnp.minimum(find(jnp.arange(N, dtype=jnp.int32)), find(used - 1))
+    expert = g if order is None else jnp.asarray(order)[g]
+    return expert.astype(jnp.int32), used.reshape(1).astype(jnp.int32)
+
+
+def _kernel(tile_expert_ref, used_ref, *refs, activation, quantized, nI):
+    del tile_expert_ref               # consumed by the index maps
     gated = activation == "swiglu"
     n_in = (4 if gated else 3) + (quantized * (3 if gated else 2))
     x_ref, *w_refs = refs[:n_in]
@@ -136,11 +189,15 @@ def _kernel(*refs, activation, quantized, nI, C, Tc):
         wu_ref, wd_ref = w_refs[:2]
         wg_ref = sg_ref = None
         su_ref, sd_ref = w_refs[2:] if quantized else (None, None)
-    c = pl.program_id(1)
-    k = pl.program_id(3)
-    i = pl.program_id(4)
+    live = pl.program_id(0) < used_ref[0]
+    k = pl.program_id(2)
+    i = pl.program_id(3)
 
-    @pl.when(i == 0)
+    @pl.when(jnp.logical_not(live))
+    def _unused():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live & (i == 0))
     def _init_acc():
         hu_ref[...] = jnp.zeros_like(hu_ref)
         if hg_ref is not None:
@@ -153,14 +210,16 @@ def _kernel(*refs, activation, quantized, nI, C, Tc):
             w = w.astype(jnp.float32) * s_ref[0, 0][None, :]
         return w
 
-    x = x_ref[0]                      # (Tc, Ti)
-    hu_ref[...] += jnp.dot(x, _load_up(wu_ref, su_ref),
-                           preferred_element_type=jnp.float32)
-    if hg_ref is not None:
-        hg_ref[...] += jnp.dot(x, _load_up(wg_ref, sg_ref),
+    @pl.when(live)
+    def _up():
+        x = x_ref[0]                  # (Tc, Ti)
+        hu_ref[...] += jnp.dot(x, _load_up(wu_ref, su_ref),
                                preferred_element_type=jnp.float32)
+        if hg_ref is not None:
+            hg_ref[...] += jnp.dot(x, _load_up(wg_ref, sg_ref),
+                                   preferred_element_type=jnp.float32)
 
-    @pl.when(i == nI - 1)
+    @pl.when(live & (i == nI - 1))
     def _finalize():
         if activation == "swiglu":
             h = jax.nn.silu(hg_ref[...]) * hu_ref[...]
@@ -168,9 +227,6 @@ def _kernel(*refs, activation, quantized, nI, C, Tc):
             h = jnp.square(jnp.maximum(hu_ref[...], 0.0))
         else:  # gelu
             h = jax.nn.gelu(hu_ref[...])
-        # mask padded capacity rows instead of computing garbage-then-truncate
-        row = c * Tc + jax.lax.broadcasted_iota(jnp.int32, h.shape, 0)
-        h = jnp.where(row < C, h, 0.0)
         wd = wd_ref[0]                # (Tk, Tj)
         if sd_ref is not None:
             wd = wd.astype(jnp.float32) * sd_ref[0, 0][None, :]
@@ -186,16 +242,18 @@ def _kernel(*refs, activation, quantized, nI, C, Tc):
             o_ref[0] += contrib
 
 
-def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
-                        s_g=None, s_u=None, s_d=None,
-                        token_tile: int = DEFAULT_TOKEN_TILE,
-                        dmodel_tile: int | None = None,
-                        dexpert_tile: int | None = None,
-                        interpret: bool | None = None):
-    """xe: (E,C,d); w_g: (E,d,m) or None; w_u: (E,d,m); w_d: (E,m,d).
-
-    Returns (E,C,d) float32.  ``w_g`` is required for swiglu and ignored
-    (never lowered as an operand) for the gateless activations.
+def ragged_moe_kernel(xs, sizes, order, w_g, w_u, w_d, *, activation: str,
+                      s_g=None, s_u=None, s_d=None,
+                      dmodel_tile: int | None = None,
+                      dexpert_tile: int | None = None,
+                      interpret: bool | None = None):
+    """xs: (N,Tc,d) rows grouped by expert; sizes: (G,) rows of each
+    group, multiples of Tc, in layout order; order: (G,) expert of each
+    group, or None for groups ``0..G-1``; w_g: (E,d,m) or None; w_u:
+    (E,d,m); w_d: (E,m,d).  Returns (N,Tc,d) float32: row r of a group
+    is that expert's FFN of ``xs`` row r, and rows past the groups are
+    zeros.  ``w_g`` is required for swiglu and ignored (never lowered as
+    an operand) for the gateless activations.
 
     Quantized streaming: when ``s_u``/``s_d`` (and ``s_g`` for swiglu)
     are given, the weight operands are int8/fp8 with per-(expert,
@@ -218,7 +276,7 @@ def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
     either that or an (Tc, m) h-scratch).  Keep d_model whole unless the
     weight blocks genuinely overflow VMEM.
     """
-    E, C, d = xe.shape
+    N, Tc, d = xs.shape
     m = w_u.shape[-1]
     gated = activation == "swiglu"
     quantized = s_u is not None
@@ -232,51 +290,100 @@ def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
         from .ops import interpret_default
         interpret = interpret_default()
 
-    Tc, Tj, Tk = resolve_tiles(C, d, m, gated=gated,
-                               x_bytes=jnp.dtype(xe.dtype).itemsize,
-                               w_bytes=jnp.dtype(w_u.dtype).itemsize,
-                               token_tile=token_tile, dmodel_tile=dmodel_tile,
-                               dexpert_tile=dexpert_tile)
+    _, Tj, Tk = resolve_tiles(Tc, d, m, gated=gated,
+                              x_bytes=jnp.dtype(xs.dtype).itemsize,
+                              w_bytes=jnp.dtype(w_u.dtype).itemsize,
+                              token_tile=Tc, dmodel_tile=dmodel_tile,
+                              dexpert_tile=dexpert_tile)
     Ti = Tj
-    pad = (-C) % Tc
-    if pad:
-        xe = jnp.pad(xe, ((0, 0), (0, pad), (0, 0)))
-    Cp = C + pad
-    nI = d // Ti
-    grid = (E, Cp // Tc, d // Tj, m // Tk, nI)
+    nJ, nK, nI = d // Tj, m // Tk, d // Ti
+    tile_expert, used = tile_map(sizes, order, Tc, N)
 
-    in_specs = [pl.BlockSpec((1, Tc, Ti), lambda e, c, j, k, i: (e, c, i))]
-    operands = [xe]
+    def live(t, j, k, i, used):
+        """Grid indices with every unused tile held at the last used
+        step's blocks, so it starts no DMA."""
+        on = t < used[0]
+        return (jnp.where(on, t, used[0] - 1), jnp.where(on, j, nJ - 1),
+                jnp.where(on, k, nK - 1), jnp.where(on, i, nI - 1))
+
+    def x_map(t, j, k, i, te, used):
+        t, _, _, i = live(t, j, k, i, used)
+        return t, 0, i
+
+    def up_map(t, j, k, i, te, used):        # w_gate / w_up (E, d, m)
+        t, _, k, i = live(t, j, k, i, used)
+        return te[t], i, k
+
+    def down_map(t, j, k, i, te, used):      # w_down (E, m, d)
+        t, j, k, _ = live(t, j, k, i, used)
+        return te[t], k, j
+
+    def up_scale_map(t, j, k, i, te, used):
+        t, _, k, _ = live(t, j, k, i, used)
+        return te[t], 0, k
+
+    def down_scale_map(t, j, k, i, te, used):
+        t, j, _, _ = live(t, j, k, i, used)
+        return te[t], 0, j
+
+    in_specs = [pl.BlockSpec((1, Tc, Ti), x_map)]
+    operands = [xs]
     if gated:
-        in_specs.append(pl.BlockSpec((1, Ti, Tk), lambda e, c, j, k, i: (e, i, k)))
+        in_specs.append(pl.BlockSpec((1, Ti, Tk), up_map))
         operands.append(w_g)
-    in_specs += [
-        pl.BlockSpec((1, Ti, Tk), lambda e, c, j, k, i: (e, i, k)),   # w_up
-        pl.BlockSpec((1, Tk, Tj), lambda e, c, j, k, i: (e, k, j)),   # w_down
-    ]
+    in_specs += [pl.BlockSpec((1, Ti, Tk), up_map),
+                 pl.BlockSpec((1, Tk, Tj), down_map)]
     operands += [w_u, w_d]
     if quantized:
         # per-output-channel scale rows, block-indexed like their weights
-        up_spec = pl.BlockSpec((1, 1, Tk), lambda e, c, j, k, i: (e, 0, k))
+        up_spec = pl.BlockSpec((1, 1, Tk), up_scale_map)
         if gated:
             in_specs.append(up_spec)
             operands.append(s_g)
-        in_specs += [up_spec,
-                     pl.BlockSpec((1, 1, Tj), lambda e, c, j, k, i: (e, 0, j))]
+        in_specs += [up_spec, pl.BlockSpec((1, 1, Tj), down_scale_map)]
         operands += [s_u, s_d]
     scratch = [pltpu.VMEM((Tc, Tk), jnp.float32)]                     # pre-act up
     if gated:
         scratch.append(pltpu.VMEM((Tc, Tk), jnp.float32))             # pre-act gate
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, activation=activation,
-                          quantized=quantized, nI=nI, C=C, Tc=Tc),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Tc, Tj), lambda e, c, j, k, i: (e, c, j)),
-        out_shape=jax.ShapeDtypeStruct((E, Cp, d), jnp.float32),
-        scratch_shapes=scratch,
+                          quantized=quantized, nI=nI),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N, nJ, nK, nI),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, Tc, Tj),
+                                   lambda t, j, k, i, te, used: (t, 0, j)),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((N, Tc, d), jnp.float32),
         interpret=interpret,
         name="streamed_moe",
-    )(*operands)
-    return out[:, :C]
+    )(tile_expert, used, *operands)
+
+
+def streamed_moe_kernel(xe, w_g, w_u, w_d, *, activation: str,
+                        s_g=None, s_u=None, s_d=None,
+                        token_tile: int = DEFAULT_TOKEN_TILE,
+                        dmodel_tile: int | None = None,
+                        dexpert_tile: int | None = None,
+                        interpret: bool | None = None):
+    """xe: (E,C,d) -> (E,C,d) float32: every expert's FFN over its C
+    rows (the padded layout FSE-DP's ring and the hybrid tiers
+    dispatch).  The ragged kernel with every expert one group of C rows,
+    padded to a multiple of the token tile; options as
+    :func:`ragged_moe_kernel`, plus ``token_tile`` (rounded by
+    :func:`token_tile_for`)."""
+    E, C, d = xe.shape
+    Tc = token_tile_for(C, token_tile)
+    pad = (-C) % Tc
+    if pad:
+        xe = jnp.pad(xe, ((0, 0), (0, pad), (0, 0)))
+    Cp = C + pad
+    out = ragged_moe_kernel(xe.reshape(E * Cp // Tc, Tc, d),
+                            jnp.full((E,), Cp, jnp.int32), None,
+                            w_g, w_u, w_d, activation=activation,
+                            s_g=s_g, s_u=s_u, s_d=s_d,
+                            dmodel_tile=dmodel_tile,
+                            dexpert_tile=dexpert_tile, interpret=interpret)
+    return out.reshape(E, Cp, d)[:, :C]

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.core import gating
 from repro.kernels import ops as kops
+from repro.kernels.streamed_moe import ragged_tiles, ragged_token_tile
 from .layers import dense_init
 from .mlp import ffn_init, ffn
 
@@ -123,39 +124,94 @@ def _reorder_experts(params, order):
     return out
 
 
+def ragged_dispatch(routing, T, E, C, Tc, order=None):
+    """Sort the T·k (token, expert) pairs into the ragged kernel's layout.
+
+    Pairs are stably sorted by their expert's rank in the trajectory
+    (``order``: expert of each rank; None = canonical), so each expert's
+    pairs stay in token order, and the pair at position >= C of its group
+    is dropped — exactly the pairs :func:`dispatch_tables` drops.  Each
+    group is padded to a multiple of the token tile ``Tc``.
+
+    Returns ``tok`` (N, Tc) int32, the token of each row (T = padding),
+    ``sizes`` (E,) rows of each group in rank order, and ``dest`` (T, k)
+    int32, each pair's row (N·Tc = dropped); N is
+    ``streamed_moe.ragged_tiles``."""
+    k = routing.indices.shape[1]
+    P = T * k
+    N = ragged_tiles(P, E, Tc)
+    expert = routing.indices.reshape(-1)
+    rank = expert
+    if order is not None:
+        ranks = jnp.arange(E, dtype=jnp.int32)
+        rank = jnp.zeros((E,), jnp.int32).at[order].set(ranks)[expert]
+    perm = jnp.argsort(rank, stable=True).astype(jnp.int32)  # sorted -> pair
+    rank_s = rank[perm]
+    counts = jnp.zeros((E,), jnp.int32).at[rank].add(1)        # per rank
+    starts = jnp.cumsum(counts) - counts
+    pos = jnp.arange(P, dtype=jnp.int32) - starts[rank_s]      # in its group
+    sizes = -(-jnp.minimum(counts, C) // Tc) * Tc
+    offsets = jnp.cumsum(sizes) - sizes
+    row = jnp.where(pos < C, offsets[rank_s] + pos, N * Tc)
+    tok = jnp.full((N * Tc,), T, jnp.int32).at[row].set(perm // k,
+                                                        mode="drop")
+    dest = jnp.zeros((P,), jnp.int32).at[perm].set(row)
+    return tok.reshape(N, Tc), sizes, dest.reshape(T, k)
+
+
+def padded_dest(routing, T, E, C):
+    """(T, k) int32: each (token, expert) pair's row in the padded
+    ``(E, C)`` layout, ``e·C`` + its position in token order (``E·C`` =
+    dropped, exactly the pairs :func:`dispatch_masks` drops)."""
+    onehot = jax.nn.one_hot(routing.indices, E, dtype=jnp.int32).sum(1)  # (T,E)
+    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1,
+                              routing.indices, axis=1)                # (T,k)
+    return jnp.where(pos < C, routing.indices * C + pos,
+                     E * C).astype(jnp.int32)
+
+
+def topk_combine(ys, dest, weights):
+    """ys: expert rows (any leading shape, last dim d) -> (T,d) fp32:
+    each token's k rows gathered by ``dest`` (dropped pairs read zero)
+    and summed with their gate ``weights`` in top-k order.  The order
+    depends on neither the trajectory, the layout nor the other rows of
+    the batch, so outputs are bit-identical across schedules, the
+    ragged and padded layouts, and batchings."""
+    flat = ys.reshape(-1, ys.shape[-1])
+    rows = jnp.take(flat, dest, axis=0, mode="fill", fill_value=0)  # (T,k,d)
+    w = weights.astype(jnp.float32)
+    y = rows[:, 0] * w[:, 0, None]
+    for j in range(1, dest.shape[1]):
+        y = y + rows[:, j] * w[:, j, None]
+    return y
+
+
 def moe_capacity(params, x2d, routing, moe: MoEConfig, activation,
                  schedule=None):
-    """Capacity dispatch -> grouped expert FFN -> combine.
+    """Ragged dispatch -> grouped expert FFN -> combine.
 
-    The route stage happened upstream (``routing``); a dynamic
-    ``schedule`` (``repro.core.trajectory``) reindexes the dispatched
-    rows and weight stacks into trajectory order for the expert FFN and
-    restores canonical order before the combine, so outputs are
-    bit-identical to the static path."""
+    The route stage happened upstream (``routing``).  The (token,
+    expert) pairs are sorted into groups by expert (:func:`ragged_dispatch`)
+    and the ragged kernel (``kernels.ops.ragged_moe``) streams only the
+    experts that have rows and computes about the routed rows.  A dynamic
+    ``schedule`` (``repro.core.trajectory``) lays the groups out in
+    trajectory order, which permutes execution and weight-streaming
+    order only: the combine (:func:`topk_combine`) sums each token's
+    rows in top-k order, so outputs are bit-identical to the static
+    path.  Capacity ``C`` keeps finite-buffer semantics; the token tile
+    follows the row count (``streamed_moe.ragged_token_tile``)."""
     from repro.core import trajectory
-    T, d = x2d.shape
+    T = x2d.shape[0]
     E = moe.num_experts
     C = capacity_of(T, moe)
     order = trajectory.resolve_order(
         schedule, lambda: gating.expert_token_counts(routing))
-    p = params if order is None else _reorder_experts(params, order)
-    if sorted_dispatch_enabled():
-        idx, wts = dispatch_tables(routing, T, E, C)
-        g_idx = idx if order is None else jnp.take(idx, order, axis=0)
-        xe = gather_dispatch(x2d, g_idx)                                   # (E,C,d)
-        ye = _expert_ffn(p, xe, activation)
-        if order is not None:
-            ye = trajectory.restore_order(order, ye)
-        return scatter_combine(ye, idx, wts, T)
-    dispatch, combine = dispatch_masks(routing, T, E, C)
-    xe = jnp.einsum("tec,td->ecd", dispatch.astype(x2d.dtype), x2d)        # (E,C,d)
-    if order is not None:
-        (xe,) = trajectory.apply_order(order, xe)
-    ye = _expert_ffn(p, xe, activation)                                    # (E,C,d) fp32
-    if order is not None:
-        ye = trajectory.restore_order(order, ye)
-    return jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
-                      ye).astype(x2d.dtype)
+    Tc = ragged_token_tile(T * routing.indices.shape[1], E)
+    tok, sizes, dest = ragged_dispatch(routing, T, E, C, Tc, order)
+    xs = jnp.take(x2d, tok, axis=0, mode="fill", fill_value=0)  # (N,Tc,d)
+    ys = kops.ragged_moe(xs, sizes, order, params.get("w_gate"),
+                         params["w_up"], params["w_down"], activation)
+    return topk_combine(ys, dest, routing.weights).astype(x2d.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -197,8 +253,9 @@ def moe_hybrid(params, x2d, routing, moe: MoEConfig, activation, *,
     when a schedule carries one, else this call's own routing counts,
     derived in-graph so the fused serving steps never retrace), the
     hottest ``hot_experts`` form the fast-tier prefix, and canonical
-    order is restored before the combine — outputs are bit-identical to
-    ``moe_capacity`` on the same routing."""
+    order is restored before the combine (:func:`topk_combine`, as
+    ``moe_capacity``'s) — outputs are bit-identical to ``moe_capacity``
+    on the same routing."""
     from repro.core import trajectory
     T, d = x2d.shape
     E = moe.num_experts
@@ -214,28 +271,28 @@ def moe_hybrid(params, x2d, routing, moe: MoEConfig, activation, *,
             .astype(jnp.int32)
     p = _reorder_experts(params, order)
     if sorted_dispatch_enabled():
-        idx, wts = dispatch_tables(routing, T, E, C)
+        idx, _ = dispatch_tables(routing, T, E, C)
         xe = gather_dispatch(x2d, jnp.take(idx, order, axis=0))     # (E,C,d)
-        ye = _expert_ffn_tiered(p, xe, activation, hot_experts)
-        ye = trajectory.restore_order(order, ye)
-        return scatter_combine(ye, idx, wts, T)
-    dispatch, combine = dispatch_masks(routing, T, E, C)
-    xe = jnp.einsum("tec,td->ecd", dispatch.astype(x2d.dtype), x2d)  # (E,C,d)
-    (xe,) = trajectory.apply_order(order, xe)
+    else:
+        dispatch, _ = dispatch_masks(routing, T, E, C)
+        xe = jnp.einsum("tec,td->ecd", dispatch.astype(x2d.dtype), x2d)
+        (xe,) = trajectory.apply_order(order, xe)                   # (E,C,d)
     ye = _expert_ffn_tiered(p, xe, activation, hot_experts)          # fp32
     ye = trajectory.restore_order(order, ye)
-    return jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
-                      ye).astype(x2d.dtype)
+    return topk_combine(ye, padded_dest(routing, T, E, C),
+                        routing.weights).astype(x2d.dtype)
 
 
 # ---------------------------------------------------------------------------
-# sorted dispatch — gather/scatter instead of one-hot einsums
+# sorted dispatch of the padded (E, C, d) layout — gather/scatter instead
+# of one-hot einsums
 #
 # The one-hot dispatch/combine einsums cost O(T·E·C·d) MXU flops (3-4x the
 # useful expert GEMMs for fine-grained MoEs); sorting token-choices by
 # expert and using gather/scatter moves the same data with zero matmul
-# flops.  Enabled via ``use_sorted_dispatch`` (a §Perf hillclimb knob; the
-# one-hot path stays as the paper-faithful capacity baseline + oracle).
+# flops.  Enabled via ``use_sorted_dispatch`` for the padded-layout paths
+# (the FSE-DP ring, the hybrid tiers); ``moe_capacity`` always sorts, into
+# the ragged layout.
 # ---------------------------------------------------------------------------
 
 import contextlib

@@ -366,6 +366,9 @@ class Engine:
         self._rng = np.random.default_rng(scfg.seed)
         self.iterations = 0
         self.stats = {"deferrals": 0, "expert_loads": 0, "expert_loads_saved": 0,
+                      # experts with >= 1 counted row, and the rest, per
+                      # MoE boundary and prefill layer (_count_streamed)
+                      "experts_streamed": 0, "experts_skipped": 0,
                       "iterations": 0, "tokens_emitted": 0,
                       "dynamic_schedules": 0,
                       "prefill_chunks": 0, "prefill_tokens": 0,
@@ -782,7 +785,17 @@ class Engine:
                     "order": paired_load_order(cnt),
                     "schedule": ("dynamic" if self.dynamic_schedule
                                  else "static")})
-                self.stats["expert_loads"] += int((cnt > 0).sum())
+                self._count_streamed(cnt)
+
+    def _count_streamed(self, counts) -> None:
+        """Expert loads at one MoE boundary or prefill layer: the experts
+        with at least one row (``expert_loads``, ``experts_streamed`` —
+        the ragged kernel streams only those) and the rest
+        (``experts_skipped``), from the counts the engine read anyway."""
+        hit = int((counts > 0).sum())
+        self.stats["expert_loads"] += hit
+        self.stats["experts_streamed"] += hit
+        self.stats["experts_skipped"] += len(counts) - hit
 
     def step(self) -> List[Tuple[str, int]]:
         """One iteration, spanned as ``engine.step``.  JAX compile-path
@@ -1099,7 +1112,7 @@ class Engine:
                 self._layer_schedules[layer] = sched
                 rec["trajectory"] = list(sched.order)
             self._record(rec)
-            self.stats["expert_loads"] += int((counts > 0).sum())
+            self._count_streamed(counts)
             if self.policy.n_threshold >= _DEFER_OFF:
                 return list(run_ffn)
             kept = []

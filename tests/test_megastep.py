@@ -64,7 +64,8 @@ def _assert_same(e0, o0, e1, o1):
             else:
                 assert a[k] == b[k], k
     for k in ("deferrals", "dynamic_schedules", "tokens_emitted",
-              "iterations", "expert_loads", "expert_loads_saved"):
+              "iterations", "expert_loads", "expert_loads_saved",
+              "experts_streamed", "experts_skipped"):
         assert e0.stats[k] == e1.stats[k], k
 
 

@@ -120,3 +120,100 @@ def test_expert_token_counts():
     r = gating.route(params["router"], x, top_k=2)
     counts = np.asarray(gating.expert_token_counts(r))
     assert counts.sum() == 20 * 2
+
+
+# ---------------------------------------------------------------------------
+# ragged dispatch: pairs sorted by expert, groups padded to the token tile
+# ---------------------------------------------------------------------------
+
+
+def _kept_pairs_tables(routing, T, E, C):
+    idx, _ = moe_mod.dispatch_tables(routing, T, E, C)
+    idx = np.asarray(idx)
+    return {(int(t), e) for e in range(E) for t in idx[e] if t < T}
+
+
+@pytest.mark.parametrize("E,k,T,cf", [(8, 2, 24, 1.0), (8, 2, 24, 0.5),
+                                      (4, 1, 16, 0.25), (16, 4, 10, 16.0)])
+@pytest.mark.parametrize("dynamic", [False, True])
+def test_ragged_dispatch_drops_what_dispatch_tables_drops(E, k, T, cf,
+                                                          dynamic):
+    """Finite capacity keeps the first C pairs of each expert in token
+    order, whatever the trajectory; every kept pair's row holds its
+    token, in a tile of its expert."""
+    from repro.core import trajectory
+    from repro.kernels.streamed_moe import ragged_tiles, tile_map
+    moe, params = _setup(E=E, k=k, cf=cf)
+    x = jax.random.normal(jax.random.PRNGKey(T), (T, 16), jnp.float32)
+    r = gating.route(params["router"], x, top_k=k)
+    C = moe_mod.capacity_of(T, moe)
+    order = None
+    if dynamic:
+        order = jnp.asarray(trajectory.build_schedule(
+            np.asarray(gating.expert_token_counts(r))).order, jnp.int32)
+    Tc = 8
+    tok, sizes, dest = moe_mod.ragged_dispatch(r, T, E, C, Tc, order)
+    N = ragged_tiles(T * k, E, Tc)
+    assert tok.shape == (N, Tc)
+    tok, dest = np.asarray(tok).reshape(-1), np.asarray(dest)
+    ind = np.asarray(r.indices)
+    kept = {(t, int(ind[t, j])) for t in range(T) for j in range(k)
+            if dest[t, j] < N * Tc}
+    assert kept == _kept_pairs_tables(r, T, E, C)
+    tile_expert, used = tile_map(sizes, order, Tc, N)
+    assert int(used[0]) * Tc == int(np.asarray(sizes).sum())
+    for t in range(T):
+        for j in range(k):
+            if dest[t, j] < N * Tc:
+                assert tok[dest[t, j]] == t
+                assert int(tile_expert[dest[t, j] // Tc]) == ind[t, j]
+    # every row that no pair owns is padding
+    assert (np.sort(tok)[len(kept):] == T).all()
+
+
+@pytest.mark.parametrize("cf", [0.5, 8.0])
+def test_capacity_dynamic_order_bit_identical(cf):
+    """A host trajectory permutes the groups and their weight streams,
+    never the values: bit-identical to the static layout, with and
+    without drops, kernel and oracle alike."""
+    from repro.core import trajectory
+    from repro.kernels import ops
+    moe, params = _setup(cf=cf)
+    x = jax.random.normal(jax.random.PRNGKey(9), (20, 16), jnp.float32)
+    r = gating.route(params["router"], x, top_k=moe.top_k)
+    sched = trajectory.build_schedule(
+        np.random.default_rng(0).integers(0, 9, moe.num_experts))
+    for kernels in (True, False):
+        with ops.use_kernels(kernels):
+            ys = moe_mod.moe_capacity(params, x, r, moe, "swiglu")
+            yd = moe_mod.moe_capacity(params, x, r, moe, "swiglu",
+                                      schedule=sched)
+        np.testing.assert_array_equal(np.asarray(ys), np.asarray(yd))
+
+
+def test_capacity_row_alone_bit_identical():
+    """Drop-free, a row's output does not depend on the rows beside it,
+    though the token tile follows the row count (8 rows alone, 16 for
+    32 rows)."""
+    from repro.kernels.streamed_moe import ragged_token_tile
+    moe, params = _setup(cf=8.0)
+    x = jax.random.normal(jax.random.PRNGKey(10), (32, 16), jnp.float32)
+    assert (ragged_token_tile(2, 8), ragged_token_tile(64, 8)) == (8, 16)
+    r = gating.route(params["router"], x, top_k=moe.top_k)
+    y = np.asarray(moe_mod.moe_capacity(params, x, r, moe, "swiglu"))
+    for t in (0, 13, 31):
+        r1 = gating.route(params["router"], x[t:t + 1], top_k=moe.top_k)
+        y1 = moe_mod.moe_capacity(params, x[t:t + 1], r1, moe, "swiglu")
+        np.testing.assert_array_equal(np.asarray(y1)[0], y[t])
+
+
+def test_capacity_one_expert_takes_every_row():
+    """Every row routed to one expert, drop-free: one group, one expert
+    streamed, the dense oracle's output."""
+    moe, params = _setup(E=2, k=1, cf=2.0)          # C = T
+    x = jnp.ones((16, 16), jnp.float32)
+    r = gating.route(params["router"], x, top_k=1)
+    assert len(set(np.asarray(r.indices).ravel().tolist())) == 1
+    y = moe_mod.moe_capacity(params, x, r, moe, "swiglu")
+    np.testing.assert_allclose(y, moe_mod.moe_dense(params, x, r, "swiglu"),
+                               rtol=2e-4, atol=2e-4)

@@ -157,7 +157,8 @@ def test_quantized_kernel_ships_scale_operands():
                           if q.primitive.name == "pallas_call"]
     assert calls, "expected a pallas_call in the jaxpr"
     avals = [v.aval for v in calls[0].invars]
-    assert len(avals) == 5                       # xe, w_u, w_d, s_u, s_d
+    # tile map, used-tile count; xe, w_u, w_d, s_u, s_d
+    assert len(avals) == 2 + 5
     assert sum(jnp.dtype(a.dtype).itemsize == 1 for a in avals) == 2
 
 

@@ -205,6 +205,28 @@ def test_trace_counts_use_gating_helper(setup):
     assert int(gating.expert_token_counts(routing, m).sum()) == cfg.moe.top_k
 
 
+@pytest.mark.parametrize("chunked", [False, True], ids=["submit", "chunked"])
+def test_expert_counters_follow_trace_counts(setup, chunked):
+    """experts_streamed / experts_skipped: per MoE boundary and prefill
+    layer, the experts with at least one counted row and the rest, from
+    the counts the trace records (no read of their own)."""
+    cfg, params = setup
+    eng = Engine(params, cfg, ServeConfig(max_batch=4, max_ctx=48,
+                                          chunk_tokens=4))
+    sub = eng.submit_chunked if chunked else eng.submit
+    for p in ((1, 2, 3, 4, 5, 6), (9, 8, 7)):
+        sub(list(p), max_new=5)
+    eng.run()
+    recs = [r for r in eng.trace if "counts" in r]
+    assert recs and (not chunked
+                     or any(r.get("phase") == "prefill" for r in recs))
+    hit = sum(int((r["counts"] > 0).sum()) for r in recs)
+    E = cfg.moe.num_experts
+    assert eng.stats["experts_streamed"] == hit == eng.stats["expert_loads"]
+    assert eng.stats["experts_skipped"] == E * len(recs) - hit
+    assert 0 < hit < E * len(recs)
+
+
 def test_serveconfig_deprecated_aliases_warn_once():
     """Satellite: moe_impl / autotune aliases emit a one-shot
     DeprecationWarning and still merge into the spec."""

@@ -182,12 +182,13 @@ def test_spec_scopes_kernels_and_dispatch():
     with kops.use_kernels(False):
         y_plain = moe_mod.moe_block(params, x, moe, "swiglu", spec="capacity")
     assert np.array_equal(np.asarray(y_ref), np.asarray(y_plain))
-    # sorted dispatch through the spec == the explicit context toggle
+    # sorted dispatch through the spec == the explicit context toggle (on
+    # a padded-layout path: capacity always sorts into the ragged layout)
     y_sorted = moe_mod.moe_block(
         params, x, moe, "swiglu",
-        spec=ExecutionSpec(strategy="capacity", sorted_dispatch=True))
+        spec=ExecutionSpec(strategy="hybrid", sorted_dispatch=True))
     with moe_mod.use_sorted_dispatch(True):
-        y_ctx = moe_mod.moe_block(params, x, moe, "swiglu", spec="capacity")
+        y_ctx = moe_mod.moe_block(params, x, moe, "swiglu", spec="hybrid")
     assert np.array_equal(np.asarray(y_sorted), np.asarray(y_ctx))
 
 

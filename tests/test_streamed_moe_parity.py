@@ -1,7 +1,9 @@
 """CPU-interpret parity for the streamed-MoE Pallas kernel and its
 ``kernels.ops`` dispatch layer: all three activations vs the jnp oracle,
-native gateless lowering, d_model/d_expert tiling, capacity-row masking,
-gradients, and the single-device model paths under use_kernels on/off."""
+native gateless lowering, d_model/d_expert tiling, capacity-row padding,
+gradients, the ragged layout (empty groups, one expert taking every
+row, a trajectory order, int8/fp8 scales, unrouted experts never read),
+and the single-device model paths under use_kernels on/off."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -59,7 +61,9 @@ def test_gateless_ships_no_placeholder_operand():
             xe, None, wu, wd, activation="gelu", interpret=True))(xe, wu, wd)
     calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert calls, "expected a pallas_call in the jaxpr"
-    assert len(calls[0].invars) == 3          # xe, w_u, w_d — no placeholder
+    # the scalar-prefetched tile map and used-tile count, then xe, w_u,
+    # w_d — no placeholder
+    assert len(calls[0].invars) == 2 + 3
 
 
 def test_swiglu_requires_gate():
@@ -91,21 +95,157 @@ def test_ops_dispatch_parity_and_grads(act):
 
 
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
-@pytest.mark.parametrize("sorted_dispatch", [False, True])
-def test_moe_capacity_kernel_parity(act, sorted_dispatch):
+@pytest.mark.parametrize("capacity_factor", [1.0, 4.0])
+def test_moe_capacity_kernel_parity(act, capacity_factor):
     """Single-device capacity path flows through the dispatch layer and is
-    bit-compatible (within fp32 tolerance) across kernel on/off."""
-    moe = MoEConfig(num_experts=4, top_k=2, d_expert=24, capacity_factor=2.0)
+    bit-compatible (within fp32 tolerance) across kernel on/off, with
+    pairs dropped at finite capacity (cf 1) and none dropped (cf = E)."""
+    moe = MoEConfig(num_experts=4, top_k=2, d_expert=24,
+                    capacity_factor=capacity_factor)
     params = moe_mod.moe_init(jax.random.PRNGKey(0), 16, moe, act, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (21, 16), jnp.float32)
     r = gating.route(params["router"], x, top_k=moe.top_k)
-    ctx = moe_mod.use_sorted_dispatch(sorted_dispatch)
-    with ctx, ops.use_kernels(True):
+    with ops.use_kernels(True):
         y_k = moe_mod.moe_capacity(params, x, r, moe, act)
-    ctx = moe_mod.use_sorted_dispatch(sorted_dispatch)
-    with ctx, ops.use_kernels(False):
+    with ops.use_kernels(False):
         y_r = moe_mod.moe_capacity(params, x, r, moe, act)
     np.testing.assert_allclose(y_k, y_r, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the ragged layout: rows grouped by expert, tiles of one expert each
+# ---------------------------------------------------------------------------
+
+
+def _ragged(group_rows, Tc=8, E=5, d=32, m=24, key=3, order=None):
+    """xs (N,Tc,d) with ``group_rows[g]`` rows in group g (zero-padded to
+    tiles, two spare tiles at the end), padded sizes, and weights."""
+    from repro.kernels.streamed_moe import SUBLANE
+    sizes = np.array([-(-n // Tc) * Tc for n in group_rows], np.int32)
+    N = int(sizes.sum()) // Tc + 2
+    ks = jax.random.split(jax.random.PRNGKey(key), 4)
+    x = np.zeros((N * Tc, d), np.float32)
+    xr = np.asarray(jax.random.normal(ks[0], (N * Tc, d), jnp.float32))
+    at = 0
+    for n, sz in zip(group_rows, sizes):
+        x[at:at + n] = xr[at:at + n]
+        at += int(sz)
+    wg = jax.random.normal(ks[1], (E, d, m), jnp.float32) * 0.1
+    wu = jax.random.normal(ks[2], (E, d, m), jnp.float32) * 0.1
+    wd = jax.random.normal(ks[3], (E, m, d), jnp.float32) * 0.1
+    assert Tc % SUBLANE == 0
+    return (jnp.asarray(x.reshape(N, Tc, d)), jnp.asarray(sizes),
+            None if order is None else jnp.asarray(order, jnp.int32),
+            wg, wu, wd)
+
+
+# rows per group (layout order) and the expert of each group
+_RAGGED_CASES = {
+    "zero_rows": ([3, 0, 11, 0, 1], None),          # experts 1, 3 have none
+    "one_expert": ([0, 0, 0, 21, 0], None),         # every row to expert 3
+    "trajectory": ([2, 9, 0, 4, 1], [3, 0, 4, 1, 2]),  # groups permuted
+}
+
+
+@pytest.mark.parametrize("act", ["swiglu", "relu2", "gelu"])
+@pytest.mark.parametrize("case", sorted(_RAGGED_CASES))
+def test_ragged_kernel_matches_ref(act, case):
+    """Every group's rows through its own expert, zero rows past the
+    groups, against the ragged_dot oracle."""
+    from repro.kernels.streamed_moe import ragged_moe_kernel
+    rows, order = _RAGGED_CASES[case]
+    xs, sizes, order, wg, wu, wd = _ragged(rows, order=order)
+    wg = wg if act == "swiglu" else None
+    got = ragged_moe_kernel(xs, sizes, order, wg, wu, wd, activation=act,
+                            interpret=True)
+    want = ref.ragged_moe_ref(xs, sizes, order, wg, wu, wd, act)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    used = int(sizes.sum())
+    assert not np.asarray(got).reshape(-1, xs.shape[-1])[used:].any()
+
+
+@pytest.mark.parametrize("case", sorted(_RAGGED_CASES))
+def test_ragged_kernel_tiled_matches_ref(case):
+    """d_model and d_expert tiled (two LANE-wide tiles each): unused
+    tiles hold every block index at the last used step's."""
+    from repro.kernels.streamed_moe import ragged_moe_kernel
+    rows, order = _RAGGED_CASES[case]
+    xs, sizes, order, wg, wu, wd = _ragged(rows, order=order, d=256, m=256)
+    got = ragged_moe_kernel(xs, sizes, order, wg, wu, wd,
+                            activation="swiglu", dmodel_tile=128,
+                            dexpert_tile=128, interpret=True)
+    want = ref.ragged_moe_ref(xs, sizes, order, wg, wu, wd, "swiglu")
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("wdt", ["int8", "fp8"])
+@pytest.mark.parametrize("act", ["swiglu", "gelu"])
+def test_ragged_kernel_quantized_matches_quant_ref(wdt, act):
+    """int8/fp8 weights with their scale blocks, indexed by the tile
+    map like the weights."""
+    rows, order = _RAGGED_CASES["trajectory"]
+    xs, sizes, order, wg, wu, wd = _ragged(rows, order=order)
+    wg = wg if act == "swiglu" else None
+    with ops.use_kernels(True):
+        got = ops.ragged_moe(xs, sizes, order, wg, wu, wd, act,
+                             weight_dtype=wdt, interpret=True)
+    want = ref.ragged_moe_quant_ref(xs, sizes, order, wg, wu, wd, act, wdt)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_kernel_never_reads_unrouted_experts():
+    """NaN weights in every expert without a group change nothing: their
+    blocks are never read (tiles past the used count included)."""
+    from repro.kernels.streamed_moe import ragged_moe_kernel
+    rows, order = _RAGGED_CASES["zero_rows"]
+    xs, sizes, _, wg, wu, wd = _ragged(rows)
+    idle = np.array([n == 0 for n in rows])
+    poison = lambda w: jnp.where(idle[:, None, None], jnp.nan, w)
+    clean = ragged_moe_kernel(xs, sizes, None, wg, wu, wd,
+                              activation="swiglu", interpret=True)
+    got = ragged_moe_kernel(xs, sizes, None, poison(wg), poison(wu),
+                            poison(wd), activation="swiglu", interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, clean)
+
+
+@pytest.mark.parametrize("act", ["swiglu", "gelu"])
+def test_ragged_ops_parity_and_grads(act):
+    """ops.ragged_moe: kernel branch (fwd + custom-VJP bwd through the
+    ragged oracle) matches the use_kernels(False) branch."""
+    rows, order = _RAGGED_CASES["trajectory"]
+    xs, sizes, order, wg, wu, wd = _ragged(rows, order=order)
+    wg = wg if act == "swiglu" else None
+
+    def loss(xs, wu, wg):
+        return jnp.sum(ops.ragged_moe(xs, sizes, order, wg, wu, wd, act,
+                                      interpret=True) ** 2)
+
+    with ops.use_kernels(True):
+        y_k = ops.ragged_moe(xs, sizes, order, wg, wu, wd, act,
+                             interpret=True)
+        g_k = jax.grad(loss, (0, 1, 2))(xs, wu, wg)
+    with ops.use_kernels(False):
+        y_r = ops.ragged_moe(xs, sizes, order, wg, wu, wd, act)
+        g_r = jax.grad(loss, (0, 1, 2))(xs, wu, wg)
+    np.testing.assert_allclose(y_k, y_r, rtol=2e-5, atol=2e-5)
+    for a, b in zip(g_k, g_r):
+        if a is not None:
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("pairs,E,want", [
+    (8, 32, 8), (48, 128, 8), (48, 64, 8), (64, 32, 8),   # decode
+    (512, 32, 32), (3072, 128, 64), (3072, 64, 128),       # prefill
+    (20000, 8, 128),                                       # capped
+])
+def test_ragged_token_tile_follows_rows(pairs, E, want):
+    from repro.kernels.streamed_moe import ragged_tiles, ragged_token_tile
+    Tc = ragged_token_tile(pairs, E)
+    assert Tc == want
+    # N tiles hold the worst case: all but one hit expert take one row
+    G = min(E, pairs)
+    assert ragged_tiles(pairs, E, Tc) >= G - 1 + -(-(pairs - G + 1) // Tc)
 
 
 def test_fse_dp_single_device_kernel_parity():

@@ -22,14 +22,19 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.ssd import ssd_intra_chunk_kernel
-from repro.kernels.streamed_moe import streamed_moe_kernel
+from repro.kernels.streamed_moe import (ragged_moe_kernel, ragged_tiles,
+                                        ragged_token_tile,
+                                        streamed_moe_kernel)
 
 # (E, d_model, d_expert) at the published widths
 MOE_WIDTHS = {
     "granite-moe-1b-a400m": (32, 1024, 512),
     "qwen3-30b-a3b": (128, 2048, 768),
     "deepseek-moe-16b": (64, 2048, 1408),
+    "kanana-2-30b-a3b": (128, 2048, 768),
 }
+TOP_K = {"granite-moe-1b-a400m": 8, "deepseek-moe-16b": 6,
+         "kanana-2-30b-a3b": 6}
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +85,38 @@ def test_streamed_moe_compiles(one_chip, arch, weights, C):
         def fn(xe, wg, wu, wd):
             return streamed_moe_kernel(xe, wg, wu, wd, activation="swiglu",
                                        interpret=False)
+    assert "tpu_custom_call" in _compiled_hlo(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("rows", [1, 8, 512])
+@pytest.mark.parametrize("arch,weights", [
+    ("granite-moe-1b-a400m", "bf16"),
+    ("deepseek-moe-16b", "bf16"),
+    ("kanana-2-30b-a3b", "bf16"),
+    ("kanana-2-30b-a3b", "fp8"),
+])
+def test_ragged_moe_compiles(one_chip, arch, weights, rows):
+    """The capacity path's ragged kernel at the serving cells' call
+    shapes: decode at batch 1 and 8 (k and 8k pairs), and a 512-token
+    prefill chunk (512k pairs), with the token tile and tile count the
+    dispatch picks for them."""
+    E, d, m = MOE_WIDTHS[arch]
+    pairs = rows * TOP_K[arch]
+    Tc = ragged_token_tile(pairs, E)
+    N = ragged_tiles(pairs, E, Tc)
+    wdt = jnp.float8_e4m3fn if weights == "fp8" else jnp.bfloat16
+    shapes = [((N, Tc, d), jnp.bfloat16), ((E,), jnp.int32),
+              ((E,), jnp.int32), ((E, d, m), wdt), ((E, d, m), wdt),
+              ((E, m, d), wdt)]
+    if weights == "fp8":
+        shapes += [((E, 1, m), jnp.float32), ((E, 1, m), jnp.float32),
+                   ((E, 1, d), jnp.float32)]
+
+    def fn(xs, sizes, order, wg, wu, wd, *scales):
+        s_g, s_u, s_d = scales or (None,) * 3
+        return ragged_moe_kernel(xs, sizes, order, wg, wu, wd,
+                                 activation="swiglu", s_g=s_g, s_u=s_u,
+                                 s_d=s_d, interpret=False)
     assert "tpu_custom_call" in _compiled_hlo(fn, one_chip, *shapes)
 
 
